@@ -84,8 +84,8 @@ def main(print_fn=print, smoke: bool = False) -> dict:
     # inside the kernel.  The accuracy column is vs the *full-precision*
     # oracle — the end-to-end error the fp8 KV tier actually adds — and
     # kernel correctness itself is the tiny gap vs the dequantized oracle.
-    kq, k_scale = ref.kv_quantize(kp, "fp8")
-    vq, v_scale = ref.kv_quantize(vp, "fp8")
+    kq, k_scale = ref.kv_quantize_pool(kp, "fp8")
+    vq, v_scale = ref.kv_quantize_pool(vp, "fp8")
     t_kern = _time(lambda: ops.paged_decode_attention(
         q, kq, vq, tables, lengths, k_scale=k_scale, v_scale=v_scale), n=reps)
     full = ref.paged_decode_attention(q, kp, vp, tables, lengths)

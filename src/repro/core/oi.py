@@ -44,6 +44,13 @@ DEVICES: dict[str, Device] = {
     "TPU-V5E": Device("TPU-V5E", 819e9, 197e12, 16e9, 200.0, 50e9),
 }
 
+# The chips this repo runs on, keyed by ``jax.Device.device_kind``: the
+# peaks a measured time is divided by.  TPU v5e: 197 TFLOP/s bf16, 819
+# GB/s HBM, 16 GB (Google Cloud documentation, "TPU v5e").
+DEVICE_KINDS: dict[str, Device] = {
+    "TPU v5 lite": DEVICES["TPU-V5E"],
+}
+
 MEM_EFF = 0.73       # §V-A measured HBM utilization of the prototype
 HPU_DYN_W = 60.0     # U55C dynamic power under load (W)
 GPU_DYN_FRAC = 1.0   # GPU dynamic power fraction of TDP when busy

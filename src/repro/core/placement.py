@@ -23,6 +23,7 @@ would over-pad.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from typing import Any
 
 from jax.sharding import PartitionSpec as P
 
@@ -127,10 +128,15 @@ class Env:
     """Everything the model code needs to know about the runtime context.
 
     ``axes`` is ``{mesh_axis_name: size}`` (empty dict = single device, no
-    sharding constraints emitted).  Threaded explicitly: no ambient-mesh
-    magic, so CPU unit tests and 512-device dry-runs share one code path.
+    sharding constraints emitted).  ``mesh`` is the device mesh those axes
+    name: serving passes it, so sharding constraints and the kernels'
+    ``shard_map`` bind to it with no ambient mesh; lowering-only callers
+    (the dry-run) may leave it ``None`` and lower inside ``with mesh:``.
+    Threaded explicitly, so CPU unit tests and 512-device dry-runs share
+    one code path.
     """
     axes: dict[str, int] = field(default_factory=dict)
+    mesh: Any = None
     kv_policy: str = "batch"
     offload: str = "hpu"        # "hpu" | "none"
     sub_batches: int = 1
@@ -144,7 +150,6 @@ class Env:
     moe_a2a: bool = False       # §Perf iter.4 (refuted on XLA:CPU: lowers
                                 # to all-gather, not all-to-all; see
                                 # EXPERIMENTS.md §Perf)
-    use_pallas: bool = False
 
     def act_rules(self) -> dict[str, tuple[str, ...]]:
         rules = activation_rules(self.sequence_parallel)

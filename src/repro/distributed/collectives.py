@@ -13,7 +13,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 Pytree = Any
@@ -29,7 +28,7 @@ def int8_psum(x_q: jax.Array, scale: jax.Array, mesh, axis: str) -> jax.Array:
         n = jax.lax.psum(jnp.ones((), jnp.float32), axis)
         return total.astype(jnp.float32) * s_max / n
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P()),

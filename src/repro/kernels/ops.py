@@ -18,8 +18,15 @@ from repro.kernels.paged_decode_attention import paged_decode_attention_pallas
 from repro.kernels.prefill_attention import flash_attention_pallas
 
 
+def on_tpu() -> bool:
+    """The one backend test: compile the kernels for the TPU there, and
+    everywhere else run them in interpret mode (tests) or not at all
+    (``core/offload.py`` serves the jnp path off the TPU)."""
+    return jax.default_backend() == "tpu"
+
+
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return not on_tpu()
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -60,7 +67,7 @@ def decode_attention(
     return out.reshape(B, Hq, D)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "return_lse"))
+@functools.partial(jax.jit, static_argnames=("scale", "return_lse", "out_dtype"))
 def paged_decode_attention(
     q: jax.Array,             # (B, Hq, D) — model layout
     k_pool: jax.Array,        # (N_blocks, Hkv, block_size, D) — kernel-native
@@ -70,15 +77,17 @@ def paged_decode_attention(
     scale: float | None = None,
     *,
     starts: jax.Array | None = None,    # (B,) first hot position
-    k_scale: jax.Array | None = None,   # (N_blocks, Hkv, block_size) f32
+    k_scale: jax.Array | None = None,   # (N_blocks, Hkv, 1, block_size) f32
     v_scale: jax.Array | None = None,
     return_lse: bool = False,
+    out_dtype=None,
 ):
     """Tiered-KV params: ``k_scale``/``v_scale`` mark the pools as
     int8/fp8 payloads dequantized inside the kernel; ``starts`` restricts
     attention to the hot window ``[start, length)``; ``return_lse``
     additionally returns the per-row log-sum-exp ``(B, Hkv, G) f32`` for
-    :func:`repro.kernels.ref.lse_merge`."""
+    :func:`repro.kernels.ref.lse_merge`.  ``out_dtype`` (default: q's)
+    lets a partial that is merged later stay float32."""
     B, Hq, D = q.shape
     N, Hkv, bs, _ = k_pool.shape
     G = Hq // Hkv
@@ -91,7 +100,7 @@ def paged_decode_attention(
         qk, k_pool, v_pool,
         block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
         scale=scale, starts=starts, k_scale=k_scale, v_scale=v_scale,
-        interpret=_interpret(),
+        out_dtype=out_dtype, interpret=_interpret(),
     )
     out = out.reshape(B, Hq, D)
     if return_lse:
@@ -123,8 +132,9 @@ def flash_attention(
     qk = jnp.swapaxes(q, 1, 2)  # (B, Hq, Sq, D)
     kk = jnp.swapaxes(k, 1, 2)
     vk = jnp.swapaxes(v, 1, 2)
-    ks = None if k_scale is None else jnp.swapaxes(k_scale, 1, 2)  # (B,Hkv,Sk)
-    vs = None if v_scale is None else jnp.swapaxes(v_scale, 1, 2)
+    # scales ride lanes in the kernel: (B, Sk, Hkv) -> (B, Hkv, 1, Sk)
+    ks = None if k_scale is None else jnp.swapaxes(k_scale, 1, 2)[:, :, None]
+    vs = None if v_scale is None else jnp.swapaxes(v_scale, 1, 2)[:, :, None]
     bq = min(block_q, Sq)
     bk = min(block_k, Sk)
     while Sq % bq:

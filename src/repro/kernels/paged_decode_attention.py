@@ -16,16 +16,21 @@ Grid: ``(B, Hkv, max_blocks)``; the block axis iterates innermost so the
 VMEM scratch accumulators carry running max/denominator per (batch, kv
 head).  Unused table entries point at physical block 0 (the engine's
 null block) — their scores are masked by ``lengths`` so the garbage they
-gather never contributes.
+gather never contributes.  A negative entry marks a block this call does
+not hold (another lane's, when the pool is split across chips): it is
+fetched as block 0 and masked whole.
 
 Tiered-KV extensions (all optional, zero-cost when unused):
 
 * **quantized pools** — when ``k_scale``/``v_scale`` pools are passed
-  (``(N_blocks, Hkv, block_size)`` f32, one absmax scale per stored
-  vector), the K/V pools hold int8 or fp8 payloads and the kernel
-  dequantizes *inside* the block loop, right after the HBM->VMEM DMA:
-  the bandwidth-bound stream moves at 1 byte/elem and widens to f32 only
-  in VMEM.
+  (``(N_blocks, Hkv, 1, block_size)`` f32, one absmax scale per stored
+  vector, positions along lanes so a block's scales are one
+  ``(1, block_size)`` tile the TPU BlockSpec rules accept), the K/V pools
+  hold int8 or fp8 payloads and the kernel dequantizes *inside* the block
+  loop, right after the HBM->VMEM DMA: the bandwidth-bound stream moves
+  at 1 byte/elem and widens to f32 only in VMEM.  A position's scale
+  multiplies its score column (K) and its probability column (V), which
+  equals scaling the stored vectors.
 * **``starts``** — per-sequence first *hot* position: positions below it
   are masked exactly like positions past ``lengths``.  This is the hot
   half of the HGCA-style hybrid: cold (host-offloaded) prefix blocks are
@@ -43,8 +48,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -78,19 +81,19 @@ def _paged_decode_kernel(
     q = q_ref[0, 0].astype(jnp.float32)          # (G, D)
     k = k_ref[0, 0].astype(jnp.float32)          # (block_size, D)
     v = v_ref[0, 0].astype(jnp.float32)
-    if quantized:
-        # per-vector absmax scales: dequant right after the VMEM load
-        k = k * ks_ref[0, 0][:, None]            # (block_size, 1)
-        v = v * vs_ref[0, 0][:, None]
 
     length = lengths_ref[b]
     start = starts_ref[b]
     k_pos = s * block_size + jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
     valid = (k_pos >= start) & (k_pos < length)   # (1, block_size)
+    valid &= tables_ref[b, s] >= 0                # block held elsewhere
 
     scores = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale                                     # (G, block_size)
+    if quantized:
+        # per-vector absmax scales, one per key column (1, block_size)
+        scores = scores * ks_ref[0, 0]
     scores = jnp.where(valid, scores, NEG_INF)
 
     m_prev = m_ref[...]
@@ -101,8 +104,9 @@ def _paged_decode_kernel(
     corr = jnp.exp(m_prev - m_new)
 
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+    pv = p * vs_ref[0, 0] if quantized else p    # V scales per value row
     acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        pv, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     m_ref[...] = m_new
 
@@ -121,12 +125,14 @@ def paged_decode_attention_pallas(
     k_pool: jax.Array,        # (N_blocks, Hkv, block_size, D)
     v_pool: jax.Array,        # (N_blocks, Hkv, block_size, D)
     block_tables: jax.Array,  # (B, max_blocks) int32, physical block ids
+                              # (-1: held elsewhere, masked)
     lengths: jax.Array,       # (B,) int32
     *,
     scale: float,
     starts: jax.Array | None = None,    # (B,) int32 first hot position
-    k_scale: jax.Array | None = None,   # (N_blocks, Hkv, block_size) f32
+    k_scale: jax.Array | None = None,   # (N_blocks, Hkv, 1, block_size) f32
     v_scale: jax.Array | None = None,
+    out_dtype=None,                     # default: q's dtype
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Returns ``(out (B,Hkv,G,D), lse (B,Hkv,G,1) f32)``."""
@@ -141,10 +147,7 @@ def paged_decode_attention_pallas(
         return (b, h, 0, 0)
 
     def _kv_idx(b, h, s, tables, lens, st):
-        return (tables[b, s], h, 0, 0)
-
-    def _scale_idx(b, h, s, tables, lens, st):
-        return (tables[b, s], h, 0)
+        return (jnp.maximum(tables[b, s], 0), h, 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, G, D), _q_idx),
@@ -154,8 +157,8 @@ def paged_decode_attention_pallas(
     operands = [q, k_pool, v_pool]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, 1, block_size), _scale_idx),
-            pl.BlockSpec((1, 1, block_size), _scale_idx),
+            pl.BlockSpec((1, 1, 1, block_size), _kv_idx),
+            pl.BlockSpec((1, 1, 1, block_size), _kv_idx),
         ]
         operands += [k_scale, v_scale]
 
@@ -181,11 +184,11 @@ def paged_decode_attention_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
+            jax.ShapeDtypeStruct((B, Hkv, G, D), out_dtype or q.dtype),
             jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(block_tables, lengths, starts.astype(jnp.int32), *operands)

@@ -14,8 +14,9 @@ call attends the chunk against all earlier cache positions while staying
 causal inside the chunk.  It is a traced scalar — serving one prompt at
 many offsets reuses a single compiled kernel.
 
-Optional ``k_scale``/``v_scale`` ((B, Hkv, Sk) f32, one absmax scale per
-stored KV vector) mark the K/V operands as int8/fp8 payloads: the kernel
+Optional ``k_scale``/``v_scale`` ((B, Hkv, 1, Sk) f32, one absmax scale
+per stored KV vector, positions along lanes) mark the K/V operands as
+int8/fp8 payloads: the kernel
 dequantizes right after the HBM->VMEM load, so a quantized KV window
 streams at 1 byte/elem and widens to f32 only in VMEM (the tiered-KV
 counterpart of the paged decode kernel's quantized pools).
@@ -29,8 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
@@ -41,7 +40,7 @@ def _flash_kernel(
     q_ref,    # (1, 1, BQ, D)
     k_ref,    # (1, 1, BK, D)
     v_ref,    # (1, 1, BK, D)
-    *rest,    # [ks_ref, vs_ref (1, 1, BK),] o_ref, m/l/acc scratch
+    *rest,    # [ks_ref, vs_ref (1, 1, 1, BK),] o_ref, m/l/acc scratch
     scale: float,
     block_q: int,
     block_k: int,
@@ -67,13 +66,12 @@ def _flash_kernel(
         q = q_ref[0, 0].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
-        if quantized:
-            # per-vector absmax scales: dequant right after the VMEM load
-            k = k * ks_ref[0, 0][:, None]
-            v = v * vs_ref[0, 0][:, None]
         scores = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale                                              # (BQ, BK)
+        if quantized:
+            # per-vector absmax scales, one per key column (1, BK)
+            scores = scores * ks_ref[0, 0]
         if causal:
             q_pos = off + iq * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0
@@ -89,8 +87,9 @@ def _flash_kernel(
             p = jnp.where(m_new > NEG_INF / 2, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        pv = p * vs_ref[0, 0] if quantized else p   # V scales per value row
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            pv, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
         m_ref[...] = m_new
 
@@ -117,7 +116,7 @@ def flash_attention_pallas(
     scale: float,
     causal: bool = True,
     q_offset: jax.Array | int = 0,
-    k_scale: jax.Array | None = None,   # (B, Hkv, Sk) f32
+    k_scale: jax.Array | None = None,   # (B, Hkv, 1, Sk) f32
     v_scale: jax.Array | None = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
@@ -139,7 +138,7 @@ def flash_attention_pallas(
         return (b, h // G, ik, 0)
 
     def _scale_idx(b, h, iq, ik, off):
-        return (b, h // G, ik)
+        return (b, h // G, 0, ik)
 
     in_specs = [
         pl.BlockSpec((1, 1, block_q, D), _q_idx),
@@ -149,8 +148,8 @@ def flash_attention_pallas(
     operands = [q, k, v]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, 1, block_k), _scale_idx),
-            pl.BlockSpec((1, 1, block_k), _scale_idx),
+            pl.BlockSpec((1, 1, 1, block_k), _scale_idx),
+            pl.BlockSpec((1, 1, 1, block_k), _scale_idx),
         ]
         operands += [k_scale, v_scale]
 
@@ -174,7 +173,7 @@ def flash_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
     )(off, *operands)

@@ -37,6 +37,15 @@ def kv_quantize(x: jax.Array, kv_dtype: str) -> tuple[jax.Array, jax.Array]:
     return q.astype(dtype), scale
 
 
+def kv_quantize_pool(pool: jax.Array, kv_dtype: str) -> tuple[jax.Array, jax.Array]:
+    """:func:`kv_quantize` of a kernel-native pool ``(..., Hkv, bs, D)``
+    into the pool layout: ``(payload (..., Hkv, bs, D), scale pool
+    (..., Hkv, 1, bs) f32)`` -- a block's scales lie along lanes, the one
+    scale-block shape the TPU compiler accepts."""
+    payload, scale = kv_quantize(pool, kv_dtype)
+    return payload, scale[..., None, :]
+
+
 def kv_dequantize(payload: jax.Array, scale: jax.Array,
                   out_dtype=jnp.bfloat16) -> jax.Array:
     """Inverse of :func:`kv_quantize`: ``payload (..., D)`` * ``scale
@@ -94,11 +103,11 @@ def gather_paged_cache(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
 
 
 def gather_paged_scales(spool: jax.Array, block_tables: jax.Array) -> jax.Array:
-    """(N_blocks, Hkv, block_size) scale pool + (B, max_blocks) tables ->
-    dense-layout (B, max_blocks*block_size, Hkv) scales."""
-    N, Hkv, bs = spool.shape
+    """(N_blocks, Hkv, 1, block_size) scale pool + (B, max_blocks) tables
+    -> dense-layout (B, max_blocks*block_size, Hkv) scales."""
+    N, Hkv, _, bs = spool.shape
     B, MB = block_tables.shape
-    return jnp.swapaxes(spool[block_tables], 2, 3).reshape(B, MB * bs, Hkv)
+    return jnp.swapaxes(spool[:, :, 0][block_tables], 2, 3).reshape(B, MB * bs, Hkv)
 
 
 def paged_decode_attention(
@@ -110,7 +119,7 @@ def paged_decode_attention(
     *,
     scale: float | None = None,
     starts: jax.Array | None = None,
-    k_scale: jax.Array | None = None,   # (N_blocks, Hkv, block_size) f32
+    k_scale: jax.Array | None = None,   # (N_blocks, Hkv, 1, block_size) f32
     v_scale: jax.Array | None = None,
     return_lse: bool = False,
 ):

@@ -13,30 +13,28 @@ matching DCN-connected pods.
 from __future__ import annotations
 
 import jax
-import numpy as np
 
 
-def compat_mesh(shape, axes):
-    """``jax.make_mesh`` with explicit-Auto axis types where the installed
-    jax supports them (``jax.sharding.AxisType`` arrived after 0.4.x);
-    older versions treat every axis as Auto already."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+def auto_mesh(shape, axes, devices=None):
+    """A mesh whose axes are all ``Auto``: GSPMD propagates shardings from
+    the placement rules (``jax.make_mesh`` defaults to ``Explicit``)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
-def make_host_mesh(model_parallel: int = 1):
-    """Whatever this host actually has (CPU tests / reduced runs)."""
-    n = jax.device_count()
+def make_host_mesh(model_parallel: int = 1, devices=None):
+    """A (data, model) mesh over ``devices`` (default: every device of
+    this host)."""
+    devices = jax.devices() if devices is None else list(devices)
+    n = len(devices)
     mp = model_parallel if n % model_parallel == 0 else 1
-    return compat_mesh((n // mp, mp), ("data", "model"))
+    return auto_mesh((n // mp, mp), ("data", "model"), devices=devices)
 
 
 def mesh_axes(mesh) -> dict[str, int]:
@@ -48,23 +46,23 @@ def replica_meshes(n_replicas: int, model_parallel: int = 1) -> list:
 
     Partitions this host's devices into ``n_replicas`` disjoint
     contiguous slices so each cluster replica (e.g. a disaggregated
-    prefill or decode engine) owns its own devices.  When the host
-    cannot be split that way — fewer devices than replicas, or a
-    non-divisible count, i.e. the single-device CPU test environment —
-    every replica shares the one host mesh instead, which keeps the
-    cluster tier runnable anywhere at the cost of device isolation.
+    prefill or decode engine) owns its own devices.  A single-device
+    host (the CPU test environment) has nothing to split: every replica
+    shares its one mesh.  A multi-device host that cannot be split
+    evenly is an error, not a silent pile-up on shared devices.
     """
     if n_replicas < 1:
         raise ValueError(f"n_replicas must be >= 1, got {n_replicas}")
-    n = jax.device_count()
-    if n % n_replicas != 0 or n < n_replicas:
-        return [make_host_mesh(model_parallel)] * n_replicas
-    per = n // n_replicas
-    mp = model_parallel if per % model_parallel == 0 else 1
     devices = jax.devices()
-    meshes = []
-    for i in range(n_replicas):
-        sl = devices[i * per:(i + 1) * per]
-        grid = np.asarray(sl).reshape(per // mp, mp)
-        meshes.append(jax.sharding.Mesh(grid, ("data", "model")))
-    return meshes
+    n = len(devices)
+    if n == 1:
+        return [make_host_mesh(model_parallel)] * n_replicas
+    if n % n_replicas != 0:
+        raise ValueError(
+            f"{n} devices cannot be split evenly into {n_replicas} replicas"
+        )
+    per = n // n_replicas
+    return [
+        make_host_mesh(model_parallel, devices[i * per:(i + 1) * per])
+        for i in range(n_replicas)
+    ]

@@ -49,8 +49,12 @@ like ``prefill,decode``): prompts are admitted to prefill-role replicas
 and their KV blocks migrate to the least-loaded decode-role replica when
 the last prefill chunk completes.  ``--decode-slots N`` gives the
 decode-role replicas a larger slot count than ``--slots`` (their block
-budget scales along).  When the host has enough devices each replica is
-placed on its own mesh slice; otherwise all replicas share the host mesh.
+budget scales along).  On a multi-device host each replica is placed on
+its own even slice of the devices (an uneven split is an error); a
+single-device host shares its one device among the replicas.
+
+The compile cache persists at ``$JAX_COMPILATION_CACHE_DIR`` when set,
+else at ``<repo>/.jax_cache``.
 
 Observability (see ``docs/observability.md``):
 
@@ -64,20 +68,24 @@ Observability (see ``docs/observability.md``):
 * ``--profile N`` samples every Nth dispatch with a fenced wall-clock
   measurement (``1`` = sync mode, times everything; ``0`` = off;
   default: 8 when ``--trace`` is given) and joins it with the analytic
-  cost model into measured MFU/MBU/bandwidth counter tracks;
+  cost model into measured MFU/MBU/bandwidth counter tracks (peaks from
+  the device's ``device_kind``; MFU/MBU are not measured on the CPU);
 * ``--dashboard N`` prints a terminal snapshot every N rounds.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
 import time
+from pathlib import Path
+from typing import Any
 
 import jax
 
 from repro.configs import SHAPES, get_config
 from repro.configs.reduced import reduce_config
 from repro.core import balance
-from repro.core.oi import DEVICES
 from repro.core.placement import Env
 from repro.launch.mesh import make_host_mesh, mesh_axes, replica_meshes
 from repro.models.registry import build_model
@@ -96,8 +104,10 @@ from repro.serving.telemetry import (
 )
 from repro.serving.workload import WORKLOADS, WorkloadDriver, build_workload
 
+REPO = Path(__file__).resolve().parents[3]
 
-def main():
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--reduced", action="store_true")
@@ -196,23 +206,57 @@ def main():
                          "with the analytic cost model into measured "
                          "MFU/MBU/bandwidth (1 = sync: every dispatch; "
                          "0 = off; default: 8 with --trace, else off)")
-    ap.add_argument("--profile-device", choices=sorted(DEVICES),
-                    default="TPU-V5E",
-                    help="device peaks used for measured MFU/MBU")
     ap.add_argument("--dashboard", type=int, default=0, metavar="N",
                     help="print a terminal snapshot every N driver rounds "
                          "(queue depth, active slots, pipeline depth, pool "
                          "util, SLO attainment, measured MFU/MBU)")
-    args = ap.parse_args()
+    return ap
 
+
+def enable_compile_cache() -> None:
+    """Keep JAX's persistent compilation cache across runs: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already uses it; otherwise
+    the cache lives at the fixed ``<repo>/.jax_cache`` (a moving path
+    would never hit).  Called by entry points only, never on import."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(REPO / ".jax_cache"))
+
+
+@dataclasses.dataclass
+class Server:
+    """What :func:`build_server` assembles from the CLI arguments."""
+
+    cfg: Any
+    plan: balance.Plan
+    model: Any
+    params: Any
+    sampler: SamplerConfig
+    mode: str                        # greedy | temperature | top-k
+    engine: Engine                   # the engine, or replica 0's
+    cluster: Cluster | None
+    tracer: Tracer | None
+    slo: SLOMonitor | None
+    profiler: Any
+
+    @property
+    def front(self):
+        """Where requests go: the cluster, or the lone engine."""
+        return self.cluster if self.cluster is not None else self.engine
+
+
+def build_server(args: argparse.Namespace, devices=None) -> Server:
+    """Build the model, its seeded weights and the engine or cluster
+    that :func:`main` serves with.  ``devices`` (default: every device
+    of this host) form the serving mesh; with more than one, the KV
+    cache and decode attention spread across them per the balancer's
+    placement policy."""
     cfg = reduce_config(args.arch) if args.reduced else get_config(args.arch)
-    mesh = make_host_mesh()
+    mesh = make_host_mesh(devices=devices)
     axes = mesh_axes(mesh)
-    plan = balance.plan(cfg, SHAPES["decode_32k"], axes or {"data": 1, "model": 1})
-    print(f"balancer: policy={plan.kv_policy} sub_batches={plan.sub_batches} "
-          f"bottleneck={plan.bottleneck} "
-          f"(t_att={plan.t_attention*1e3:.2f}ms t_lin={plan.t_linear*1e3:.2f}ms)")
-    env = Env(axes=axes if mesh.devices.size > 1 else {}, kv_policy=plan.kv_policy)
+    plan = balance.plan(cfg, SHAPES["decode_32k"], axes)
+    sharded = mesh.devices.size > 1
+    env = Env(axes=axes if sharded else {}, mesh=mesh if sharded else None,
+              kv_policy=plan.kv_policy)
     model = build_model(cfg, env)
     params = model.init(jax.random.key(0))
 
@@ -258,7 +302,7 @@ def main():
     sample_every = args.profile
     if sample_every is None:
         sample_every = 8 if args.trace else 0
-    profiler = make_profiler(sample_every, device=args.profile_device)
+    profiler = make_profiler(sample_every)
     roles = parse_roles(args.role_map, args.replicas) if args.role_map else None
     role_kw = ({"decode": {"n_slots": args.decode_slots}}
                if args.decode_slots else None)
@@ -266,14 +310,14 @@ def main():
     if args.replicas > 1:
         meshes = replica_meshes(args.replicas)
         if len({id(m) for m in meshes}) > 1:
-            # enough devices for disjoint per-replica mesh slices: give
-            # each replica engine a model built against its own slice
+            # disjoint per-replica mesh slices: each replica engine gets
+            # a model built against its own slice, and places its
+            # weights and pools there
             def model_factory(i, _meshes=meshes):
-                ax = mesh_axes(_meshes[i])
-                env_i = Env(
-                    axes=ax if _meshes[i].devices.size > 1 else {},
-                    kv_policy=plan.kv_policy,
-                )
+                m = _meshes[i]
+                split = m.devices.size > 1
+                env_i = Env(axes=mesh_axes(m) if split else {},
+                            mesh=m, kv_policy=plan.kv_policy)
                 return build_model(cfg, env_i)
     cluster = (
         Cluster(model, params, args.replicas, route=args.route, tracer=tracer,
@@ -286,7 +330,21 @@ def main():
            else Engine(model, params, tracer=tracer,
                        profiler=profiler if profiler.enabled else None,
                        **engine_kw))
-    serv = cluster if cluster else eng
+    return Server(cfg=cfg, plan=plan, model=model, params=params,
+                  sampler=sampler, mode=mode, engine=eng, cluster=cluster,
+                  tracer=tracer, slo=slo, profiler=profiler)
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    srv = build_server(args)
+    plan, cfg, sampler, mode = srv.plan, srv.cfg, srv.sampler, srv.mode
+    print(f"balancer: policy={plan.kv_policy} sub_batches={plan.sub_batches} "
+          f"bottleneck={plan.bottleneck} "
+          f"(t_att={plan.t_attention*1e3:.2f}ms t_lin={plan.t_linear*1e3:.2f}ms)")
+    cluster, eng, serv = srv.cluster, srv.engine, srv.front
+    tracer, slo, profiler = srv.tracer, srv.slo, srv.profiler
     arrivals = build_workload(
         args.workload, args.requests, vocab=cfg.vocab, max_seq=args.max_seq,
         max_new=args.max_new, seed=args.workload_seed,

@@ -193,7 +193,9 @@ def paged_cache_defs(
     Tiered-KV extensions: ``kv_dtype`` in {"fp8", "int8"} stores the
     pool quantized with per-vector f32 absmax scale pools
     (``k_scale``/``v_scale``, one scale per stored (head, position)
-    vector — ~``4/(2*Dh)`` relative overhead); ``host_blocks > 0`` adds a
+    vector — ~``4/(2*Dh)`` relative overhead — laid out ``(blocks,
+    kv_heads, 1, block)`` so a block's scales are the lane-major tile the
+    kernel's BlockSpec reads); ``host_blocks > 0`` adds a
     host-tier pool (``host_k``/``host_v`` + per-slot ``host_tables`` and
     ``cold_lengths``) holding spilled cold prefix blocks, with host id 0
     reserved as the null block like the device pool.  Host leaves are
@@ -216,8 +218,8 @@ def paged_cache_defs(
     quant = kv_dtype in ("fp8", "int8")
     if quant:
         sc = ParamDef(
-            (L, n_blocks, Hkv, block_size),
-            ("layers", "kv_blocks", "kv_heads", "kv_seq"),
+            (L, n_blocks, Hkv, 1, block_size),
+            ("layers", "kv_blocks", "kv_heads", None, "kv_seq"),
             "zeros",
         )
         defs["k_scale"] = sc
@@ -236,8 +238,8 @@ def paged_cache_defs(
         defs["cold_lengths"] = ParamDef((n_slots,), ("kv_batch",), "zeros")
         if quant:
             hsc = ParamDef(
-                (L, host_blocks + 1, Hkv, block_size),
-                ("layers", None, "kv_heads", "kv_seq"),
+                (L, host_blocks + 1, Hkv, 1, block_size),
+                ("layers", None, "kv_heads", None, "kv_seq"),
                 "zeros",
             )
             defs["host_k_scale"] = hsc
@@ -326,8 +328,8 @@ def paged_decode_step(cfg, env: Env, params, cache, tokens):
         if quant:
             kq, ksc = ref.kv_quantize(k, quant)
             vq, vsc = ref.kv_quantize(v, quant)
-            ks_l = xs["ks"].at[phys, :, off].set(ksc)
-            vs_l = xs["vs"].at[phys, :, off].set(vsc)
+            ks_l = xs["ks"].at[phys, :, 0, off].set(ksc)
+            vs_l = xs["vs"].at[phys, :, 0, off].set(vsc)
             k_l = k_l.at[phys, :, off].set(kq)
             v_l = v_l.at[phys, :, off].set(vq)
         else:

@@ -59,6 +59,8 @@ from __future__ import annotations
 import re
 from collections import deque
 
+import jax
+
 from repro.serving.cluster.router import Router
 from repro.serving.cluster.stats import ClusterStats, ReplicaStats
 from repro.serving.engine import Engine, Request
@@ -114,6 +116,25 @@ def parse_roles(spec, n_replicas: int) -> list[str]:
     return roles
 
 
+def _check_disjoint(engines) -> None:
+    """On a multi-device host every replica must own its devices: two
+    replicas on one device would silently halve each other's memory and
+    time.  A single-device host (the CPU tests) shares its one device."""
+    if jax.device_count() == 1 or len(engines) == 1:
+        return
+    seen: set = set()
+    for i, eng in enumerate(engines):
+        mesh = eng.model.env.mesh
+        devs = set(mesh.devices.flat) if mesh is not None else {jax.devices()[0]}
+        if devs & seen:
+            raise ValueError(
+                f"replica {i} shares devices with an earlier replica: on a "
+                "multi-device host give each replica its own mesh slice "
+                "(launch.mesh.replica_meshes + model_factory)"
+            )
+        seen |= devs
+
+
 class Cluster:
     def __init__(
         self,
@@ -145,6 +166,7 @@ class Cluster:
                 Engine(mdl, params, tracer=self.tracer,
                        profiler=self.profiler, replica=i, role=role, **kw)
             )
+        _check_disjoint(self.engines)
         self.router = Router(self.engines, route, tracer=self.tracer,
                              roles=self.roles)
         self._prefill_idx = [i for i, r in enumerate(self.roles)

@@ -109,6 +109,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core.pipeline import pipelined_step
 from repro.models.registry import Model
@@ -307,7 +309,11 @@ class Engine:
         role: str = "mixed",
     ):
         self.model = model
-        self.params = params
+        # a model built on a mesh serves from it: weights, pools and
+        # staging are committed to its devices per the placement rules
+        # (a cluster replica's mesh is its own slice of the host)
+        self.mesh = model.env.mesh
+        self.params = self.place(params, model.param_specs())
         self.max_seq = max_seq
         self.sampler = sampler
         self.cache_kind = cache_kind
@@ -414,9 +420,11 @@ class Engine:
                 )
             self.pool = BlockPool(self.n_blocks, block_size, host_blocks=host_blocks)
             self.manager = PagedCacheManager(self.pool, n_slots, self.max_blocks)
-            self.cache = model.init_paged_cache(
-                n_slots, self.n_blocks, block_size, self.max_blocks,
-                kv_dtype=kv_dtype, host_blocks=host_blocks,
+            pool_kw = dict(kv_dtype=kv_dtype, host_blocks=host_blocks)
+            shape = (n_slots, self.n_blocks, block_size, self.max_blocks)
+            self.cache = self.place(
+                model.init_paged_cache(*shape, **pool_kw),
+                model.paged_cache_specs(*shape, **pool_kw),
             )
             self._decode = jax.jit(model.paged_decode_step)
             if async_mode:
@@ -427,7 +435,8 @@ class Engine:
                 else:
                     self._decode_sampled = self._wrap_sampled(model.paged_decode_step)
         elif cache_kind == "dense":
-            self.cache = model.init_cache(n_slots, max_seq)
+            self.cache = self.place(model.init_cache(n_slots, max_seq),
+                                    model.cache_specs(n_slots, max_seq))
             step = pipelined_step(model.decode_step, sub_batches)
             self._decode = jax.jit(step)
             if async_mode:
@@ -465,6 +474,17 @@ class Engine:
             self.d_cache = draft_model.init_cache(n_slots, max_seq)
             self._draft_prefill = jax.jit(draft_model.prefill_step)
             self._init_spec()
+
+    def place(self, tree: Pytree, specs: Pytree) -> Pytree:
+        """Commit ``tree`` to this engine's mesh, leaf by leaf per
+        ``specs`` (no-op without a mesh: the default device serves)."""
+        if self.mesh is None:
+            return tree
+        shardings = jax.tree.map(
+            lambda s: NamedSharding(self.mesh, s), specs,
+            is_leaf=lambda x: isinstance(x, P),
+        )
+        return jax.device_put(tree, shardings)
 
     @staticmethod
     def _wrap_sampled(base_step):
@@ -504,7 +524,9 @@ class Engine:
             # here, completed blocks flush into the pool.  Two lanes
             # (batch 2) so a boundary-packed second prompt can stage its
             # chunks while the finishing prompt still owns its lane.
-            self.staging = model.init_cache(2, self.max_blocks * self.block_size)
+            span = self.max_blocks * self.block_size
+            self.staging = self.place(model.init_cache(2, span),
+                                      model.cache_specs(2, span))
 
         if not self.async_mode:
             self._solo = jax.jit(model.prefill_step)
@@ -1055,16 +1077,13 @@ class Engine:
         return slot
 
     def _localize(self, payload: Pytree) -> Pytree:
-        """Move a migration payload onto this engine's device (no-op when
-        source and destination share one, e.g. single-host CPU runs;
-        multi-device *sharded* pools would need a resharding transfer and
-        are out of scope for migration)."""
-        anchor = self.cache["lengths"]
-        devs = anchor.devices() if hasattr(anchor, "devices") else set()
-        if len(devs) == 1:
-            (dev,) = devs
-            return jax.tree.map(lambda a: jax.device_put(a, dev), payload)
-        return payload
+        """Move a migration payload onto this engine's devices: replicated
+        over its mesh (the block scatter then lands each block on the
+        lane that holds it), or onto its one device."""
+        if self.mesh is not None:
+            return jax.device_put(payload, NamedSharding(self.mesh, P()))
+        (dev,) = self.cache["lengths"].devices()
+        return jax.tree.map(lambda a: jax.device_put(a, dev), payload)
 
     # ---------------------------------------------- cluster refold leveling
     def can_admit_next(self) -> bool:

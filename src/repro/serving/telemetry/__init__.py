@@ -42,6 +42,7 @@ from repro.serving.telemetry.profiler import (
     DispatchProfiler,
     NullDispatchProfiler,
     ProfileSample,
+    device_peaks,
     make_profiler,
 )
 from repro.serving.telemetry.slo import SLOMonitor
@@ -80,6 +81,7 @@ __all__ = [
     "build_request_trees",
     "cluster_registry",
     "engine_registry",
+    "device_peaks",
     "make_profiler",
     "percentile",
     "render_dashboard",

@@ -135,8 +135,9 @@ def to_chrome_trace(tracer: Tracer) -> dict:
         if rec.measured_s is not None:
             # sampled-profiler join: the measured twin of the analytic
             # oi track, graphed by Perfetto as the live Fig-8 view
-            counters["measured_mfu"] = rec.measured_mfu
-            counters["measured_mbu"] = rec.measured_mbu
+            if rec.measured_mfu is not None:   # None: no device peaks
+                counters["measured_mfu"] = rec.measured_mfu
+                counters["measured_mbu"] = rec.measured_mbu
             counters["achieved_gbps"] = rec.achieved_gbps
         for cname, val in counters.items():
             events.append({

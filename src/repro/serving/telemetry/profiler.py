@@ -11,6 +11,10 @@ the dispatch's analytic cost:
 * ``measured_mbu``  = bytes / (seconds * device peak HBM B/s)
 * ``achieved_gbps`` = bytes / seconds / 1e9
 
+The peaks come from the device the run is on (:func:`device_peaks`,
+keyed by ``device_kind``).  A backend with no peaks in the table (the
+CPU) keeps the seconds and leaves MFU/MBU unset: not measured.
+
 Sampling contract
 -----------------
 Fencing a dispatch drains the async dispatch-ahead pipeline (the *pre*
@@ -37,7 +41,23 @@ import time
 
 import jax
 
-from repro.core.oi import DEVICES, Device
+from repro.core.oi import DEVICE_KINDS, DEVICES, Device
+
+
+def device_peaks(device=None) -> Device | None:
+    """Peak FLOP/s and HBM bytes/s of ``device`` (default: the first
+    device JAX sees), looked up by its ``device_kind``.  ``None`` off the
+    TPU, where no peak applies; a TPU kind missing from
+    :data:`~repro.core.oi.DEVICE_KINDS` is an error, never a default."""
+    device = jax.devices()[0] if device is None else device
+    if device.platform != "tpu":
+        return None
+    if device.device_kind not in DEVICE_KINDS:
+        raise ValueError(
+            f"no peaks for TPU kind {device.device_kind!r}: add it to "
+            "repro.core.oi.DEVICE_KINDS with its source"
+        )
+    return DEVICE_KINDS[device.device_kind]
 
 
 class NullDispatchProfiler:
@@ -76,8 +96,8 @@ class ProfileSample:
     flops: float                # analytic FLOPs (DispatchCostModel)
     bytes: float                # analytic HBM bytes
     oi: float                   # flops / bytes
-    measured_mfu: float
-    measured_mbu: float
+    measured_mfu: float | None  # None: no device peaks (not measured)
+    measured_mbu: float | None
     achieved_gbps: float
 
     def as_dict(self) -> dict:
@@ -95,10 +115,16 @@ class DispatchProfiler:
 
     enabled = True
 
-    def __init__(self, sample_every: int = 8, device: str | Device = "TPU-V5E"):
+    def __init__(self, sample_every: int = 8,
+                 device: str | Device | None = None):
+        """``device`` names the peaks explicitly (a :data:`DEVICES` key
+        or a :class:`Device`); by default they are read from the device
+        the run is on (:func:`device_peaks`)."""
         if sample_every < 1:
             raise ValueError(f"sample_every must be >= 1, got {sample_every}")
         self.sample_every = sample_every
+        if device is None:
+            device = device_peaks()
         self.device = DEVICES[device] if isinstance(device, str) else device
         self.samples: list[ProfileSample] = []
         self._n = 0             # dispatches seen (sampled or not)
@@ -137,8 +163,10 @@ class DispatchProfiler:
         if dt is None or record is None:
             return
         dt = max(dt, 1e-9)
-        mfu = record.flops / (dt * self.device.flops)
-        mbu = record.bytes / (dt * self.device.bw)
+        mfu = mbu = None
+        if self.device is not None:
+            mfu = record.flops / (dt * self.device.flops)
+            mbu = record.bytes / (dt * self.device.bw)
         gbps = record.bytes / dt / 1e9
         record.measured_s = dt
         record.measured_mfu = mfu
@@ -167,10 +195,11 @@ class DispatchProfiler:
                 "n": float(n),
                 "seconds": sum(s.seconds for s in ss) / n,
                 "oi": sum(s.oi for s in ss) / n,
-                "measured_mfu": sum(s.measured_mfu for s in ss) / n,
-                "measured_mbu": sum(s.measured_mbu for s in ss) / n,
                 "achieved_gbps": sum(s.achieved_gbps for s in ss) / n,
             }
+            if self.device is not None:
+                out[key]["measured_mfu"] = sum(s.measured_mfu for s in ss) / n
+                out[key]["measured_mbu"] = sum(s.measured_mbu for s in ss) / n
         return out
 
     def register(self, reg) -> None:
@@ -181,12 +210,13 @@ class DispatchProfiler:
         if not self.samples:
             return
         n = len(self.samples)
-        reg.gauge("measured_mfu").set(
-            sum(s.measured_mfu for s in self.samples) / n
-        )
-        reg.gauge("measured_mbu").set(
-            sum(s.measured_mbu for s in self.samples) / n
-        )
+        if self.device is not None:
+            reg.gauge("measured_mfu").set(
+                sum(s.measured_mfu for s in self.samples) / n
+            )
+            reg.gauge("measured_mbu").set(
+                sum(s.measured_mbu for s in self.samples) / n
+            )
         reg.gauge("achieved_gbps").set(
             sum(s.achieved_gbps for s in self.samples) / n
         )
@@ -199,25 +229,29 @@ class DispatchProfiler:
         if not self.samples:
             return "measured: no samples yet"
         n = len(self.samples)
+        bw = sum(s.achieved_gbps for s in self.samples) / n
+        tail = f"bw={bw:.1f}GB/s (n={n}, every {self.sample_every})"
+        if self.device is None:
+            return f"measured[no device peaks]: mfu/mbu not measured {tail}"
         mfu = sum(s.measured_mfu for s in self.samples) / n
         mbu = sum(s.measured_mbu for s in self.samples) / n
-        bw = sum(s.achieved_gbps for s in self.samples) / n
         return (f"measured[{self.device.name}]: mfu={mfu:.4f} mbu={mbu:.4f} "
-                f"bw={bw:.1f}GB/s (n={n}, every {self.sample_every})")
+                f"{tail}")
 
 
-def make_profiler(sample_every: int,
-                  device: str = "TPU-V5E") -> DispatchProfiler | NullDispatchProfiler:
+def make_profiler(sample_every: int) -> DispatchProfiler | NullDispatchProfiler:
     """CLI helper: ``sample_every <= 0`` means disabled (NULL profiler),
-    ``1`` is sync mode, ``N`` fences every Nth dispatch."""
+    ``1`` is sync mode, ``N`` fences every Nth dispatch; peaks come from
+    the device the run is on."""
     if sample_every <= 0:
         return NULL_PROFILER
-    return DispatchProfiler(sample_every=sample_every, device=device)
+    return DispatchProfiler(sample_every=sample_every)
 
 
 __all__ = [
     "NULL_PROFILER",
     "DispatchProfiler",
+    "device_peaks",
     "NullDispatchProfiler",
     "ProfileSample",
     "make_profiler",

@@ -19,20 +19,9 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 Pytree = Any
-
-
-def _pcast_varying(x: jax.Array, axis: str) -> jax.Array:
-    """Mark ``x`` as stage-varying inside shard_map.  ``jax.lax.pcast``
-    only exists on jax versions with varying-manual-axes checking; older
-    versions treat every value as varying already, so identity is exact."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, (axis,), to="varying")
 
 
 def split_stages(stacked_params: Pytree, n_stages: int) -> Pytree:
@@ -67,8 +56,8 @@ def pipeline_forward(
         stage = jax.lax.axis_index(axis)
         micro = x_local  # only stage 0 actually consumes it
         # carries become stage-varying inside the loop; mark them up front
-        buf = _pcast_varying(jnp.zeros_like(x_local[0]), axis)
-        outs = _pcast_varying(jnp.zeros_like(x_local), axis)
+        buf = jax.lax.pcast(jnp.zeros_like(x_local[0]), (axis,), to="varying")
+        outs = jax.lax.pcast(jnp.zeros_like(x_local), (axis,), to="varying")
 
         def step(t, carry):
             buf, outs = carry
@@ -97,7 +86,7 @@ def pipeline_forward(
         mask = (stage == n_stages - 1).astype(outs.dtype)
         return jax.lax.psum(outs * mask, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(P(axis), P()),

@@ -3,7 +3,7 @@ restore-into-different-sharding (elastic path)."""
 import os
 
 import jax
-from repro.launch.mesh import compat_mesh
+from repro.launch.mesh import auto_mesh
 import jax.numpy as jnp
 import numpy as np
 
@@ -74,7 +74,7 @@ def test_restore_latest_and_specific(tmp_path):
 
 def test_restore_with_shardings(tmp_path):
     """Elastic path: restore placing leaves with explicit shardings."""
-    mesh = compat_mesh((1,), ("data",))
+    mesh = auto_mesh((1,), ("data",))
     sh = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
     ck = Checkpointer(str(tmp_path))
     state = _state()

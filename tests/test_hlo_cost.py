@@ -18,8 +18,6 @@ def test_loopfree_matches_xla_cost_analysis():
     c = _compile(f, x, x)
     got = analyze(c.as_text())
     xla = c.cost_analysis()
-    if isinstance(xla, list):   # jax < 0.5 returns one dict per device
-        xla = xla[0]
     assert got.flops == pytest.approx(xla["flops"], rel=0.01)
 
 

@@ -93,8 +93,8 @@ def test_paged_kernel_quantized_matches_oracle(kv_dtype):
     tables = jnp.asarray(tables)
     lengths = jnp.asarray(lens, jnp.int32)
 
-    kq, k_scale = ref.kv_quantize(kp, kv_dtype)
-    vq, v_scale = ref.kv_quantize(vp, kv_dtype)
+    kq, k_scale = ref.kv_quantize_pool(kp, kv_dtype)
+    vq, v_scale = ref.kv_quantize_pool(vp, kv_dtype)
     out = ops.paged_decode_attention(q, kq, vq, tables, lengths,
                                      k_scale=k_scale, v_scale=v_scale)
     exp = ref.paged_decode_attention(q, kq, vq, tables, lengths,

@@ -24,6 +24,7 @@ from repro.serving.telemetry import (
     Span,
     Tracer,
     cluster_registry,
+    device_peaks,
     make_profiler,
     percentile,
     render_dashboard,
@@ -263,6 +264,41 @@ def test_profiler_joins_measured_with_analytic(model_params):
     assert snap["dispatch_seconds_count"] == len(prof.samples)
 
 
+def test_profiler_without_device_peaks_reports_not_measured(model_params):
+    """Off the TPU no peaks apply: seconds and bandwidth are kept, and
+    MFU/MBU stay unset everywhere they would be reported."""
+    model, params = model_params
+    assert device_peaks() is None            # the tests run on the CPU
+    prof = DispatchProfiler(sample_every=1)
+    assert prof.device is None
+    tracer = Tracer()
+    _, eng = _serve(model, params, PROMPTS[:2], schedule="hybrid",
+                    prefill_chunk=8, profiler=prof, tracer=tracer)
+    assert prof.samples and all(
+        s.seconds > 0 and s.measured_mfu is None and s.measured_mbu is None
+        for s in prof.samples)
+    assert all("measured_mfu" not in row for row in prof.summary().values())
+    reg = MetricsRegistry()
+    prof.register(reg)
+    snap = reg.snapshot()
+    assert "measured_mfu" not in snap and snap["achieved_gbps"] > 0
+    assert "not measured" in prof.describe()
+    names = {e["name"] for e in to_chrome_trace(tracer)["traceEvents"]
+             if e["ph"] == "C"}
+    assert "achieved_gbps" in names and "measured_mfu" not in names
+
+
+def test_device_peaks_from_device_kind():
+    class _Dev:
+        def __init__(self, platform, kind):
+            self.platform, self.device_kind = platform, kind
+
+    assert device_peaks(_Dev("tpu", "TPU v5 lite")) is DEVICES["TPU-V5E"]
+    assert device_peaks(_Dev("cpu", "cpu")) is None
+    with pytest.raises(ValueError, match="no peaks"):
+        device_peaks(_Dev("tpu", "TPU v99"))
+
+
 def test_profiler_sampling_rate(model_params):
     """sample_every=N fences ~1/N of dispatches, and unsampled steps
     carry no measured fields."""
@@ -284,7 +320,8 @@ def test_profiler_sampling_rate(model_params):
 def test_measured_counter_tracks_in_trace(model_params):
     model, params = model_params
     tracer = Tracer(wall=True)
-    prof = DispatchProfiler(sample_every=2)
+    # explicit peaks: the CPU has none, and the tracks under test need them
+    prof = DispatchProfiler(sample_every=2, device="TPU-V5E")
     _, _ = _serve(model, params, PROMPTS, schedule="hybrid",
                   prefill_chunk=8, tracer=tracer, profiler=prof)
     obj = to_chrome_trace(tracer)

@@ -11,10 +11,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import json
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.launch.mesh import compat_mesh
+from repro.launch.mesh import auto_mesh
 from repro.training.pipeline_pp import pipeline_forward, sequential_reference, split_stages
 
-mesh = compat_mesh((4,), ("stage",))
+mesh = auto_mesh((4,), ("stage",))
 L, D = 8, 16
 n_micro, B, S = 6, 2, 4
 key = jax.random.key(0)

@@ -17,9 +17,9 @@ from repro.configs.reduced import reduce_config
 from repro.core.placement import Env
 from repro.models.registry import build_model
 from repro.launch import specs as S
-from repro.launch.mesh import compat_mesh, mesh_axes
+from repro.launch.mesh import auto_mesh, mesh_axes
 
-mesh = compat_mesh((4, 2), ("data", "model"))
+mesh = auto_mesh((4, 2), ("data", "model"))
 axes = mesh_axes(mesh)
 cfg = reduce_config("llama3.2-1b").with_overrides(
     n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_ff=128, vocab=256, head_dim=16)
