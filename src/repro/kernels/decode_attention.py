@@ -121,6 +121,7 @@ def decode_attention_pallas(
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="decode_attention",
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(

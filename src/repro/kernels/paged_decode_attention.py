@@ -183,6 +183,7 @@ def paged_decode_attention_pallas(
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="paged_decode_attention",
         out_shape=[
             jax.ShapeDtypeStruct((B, Hkv, G, D), out_dtype or q.dtype),
             jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),

@@ -171,6 +171,7 @@ def flash_attention_pallas(
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name="flash_attention",
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(

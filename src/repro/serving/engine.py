@@ -103,6 +103,7 @@ cluster drives this; a refused import simply decodes in place.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections import deque
 from typing import Any
 
@@ -281,6 +282,20 @@ class _PendingStep:
     charge: int = 1                      # in-flight charges per batch slot
 
 
+def _step_program(kind: str, fn, **jit_kw):
+    """Jit one step program as ``step_<kind>`` (``kind`` as in
+    ``StepRecord.kind``), so the device trace's program line reads
+    ``jit_step_<kind>`` whichever model entry point or wrapper is behind
+    it."""
+
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = f"step_{kind}"
+    return jax.jit(program, **jit_kw)
+
+
 class Engine:
     def __init__(
         self,
@@ -392,7 +407,7 @@ class Engine:
             DispatchCostModel(model.cfg) if self._telemetry else None
         )
 
-        self._prefill = jax.jit(model.prefill)
+        self._prefill = _step_program("prefill", model.prefill)
         if cache_kind != "paged" and (kv_dtype != "bf16" or host_blocks):
             raise ValueError(
                 "kv_dtype / host_blocks are paged-cache features "
@@ -426,11 +441,12 @@ class Engine:
                 model.init_paged_cache(*shape, **pool_kw),
                 model.paged_cache_specs(*shape, **pool_kw),
             )
-            self._decode = jax.jit(model.paged_decode_step)
+            self._decode = _step_program("decode", model.paged_decode_step)
             if async_mode:
                 if model.paged_decode_sample_step is not None:
-                    self._decode_sampled = jax.jit(
-                        model.paged_decode_sample_step, static_argnames=("sampler",)
+                    self._decode_sampled = _step_program(
+                        "decode", model.paged_decode_sample_step,
+                        static_argnames=("sampler",),
                     )
                 else:
                     self._decode_sampled = self._wrap_sampled(model.paged_decode_step)
@@ -438,11 +454,12 @@ class Engine:
             self.cache = self.place(model.init_cache(n_slots, max_seq),
                                     model.cache_specs(n_slots, max_seq))
             step = pipelined_step(model.decode_step, sub_batches)
-            self._decode = jax.jit(step)
+            self._decode = _step_program("decode", step)
             if async_mode:
                 if sub_batches == 1 and model.decode_sample_step is not None:
-                    self._decode_sampled = jax.jit(
-                        model.decode_sample_step, static_argnames=("sampler",)
+                    self._decode_sampled = _step_program(
+                        "decode", model.decode_sample_step,
+                        static_argnames=("sampler",),
                     )
                 else:
                     self._decode_sampled = self._wrap_sampled(step)
@@ -452,7 +469,10 @@ class Engine:
         # async pipeline state (allocated in both modes so shared helpers
         # like _prepare_append can test `self._pending` unconditionally)
         self._pending: deque[_PendingStep] = deque()
-        self._first_pending: list[tuple[Request, jax.Array]] = []
+        # admission prefills' first tokens, fetched with the step stream:
+        # (request, device token, step id of the prefill)
+        self._first_pending: list[tuple[Request, jax.Array, int]] = []
+        self._dispatched: tuple[int, str] | None = None    # (step, kind)
         if async_mode:
             self._tok_state = jnp.zeros((n_slots,), jnp.int32)
             self._eos_dev = jnp.full((n_slots,), -1, jnp.int32)
@@ -496,7 +516,7 @@ class Engine:
             tok = sample_on_device(logits, rng, sampler)
             return tok, tok == eos_ids, new_cache
 
-        return jax.jit(_sampled, static_argnames=("sampler",))
+        return _step_program("decode", _sampled, static_argnames=("sampler",))
 
     def _init_hybrid(self, sub_batches: int) -> None:
         model = self.model
@@ -529,7 +549,7 @@ class Engine:
                                       model.cache_specs(2, span))
 
         if not self.async_mode:
-            self._solo = jax.jit(model.prefill_step)
+            self._solo = _step_program("solo", model.prefill_step)
             if self.cache_kind == "paged":
 
                 def _fused(params, cache, staging, dec_tokens, pre_tokens, lane, off, nv):
@@ -555,8 +575,8 @@ class Engine:
                     lb, staging = model.prefill_step(params, staging, tokB, laneB, offB, nvB)
                     return la, lb, staging
 
-                self._fused2 = jax.jit(_fused2)
-                self._solo2 = jax.jit(_solo2)
+                self._fused2 = _step_program("fused2", _fused2)
+                self._solo2 = _step_program("solo2", _solo2)
             else:
 
                 def _fused(params, cache, dec_tokens, pre_tokens, slot, off, nv):
@@ -588,10 +608,10 @@ class Engine:
                     lb, cache = model.prefill_step(params, cache, tokB, slotB, offB, nvB)
                     return la, lb, cache
 
-                self._fused2 = jax.jit(_fused2)
-                self._solo2 = jax.jit(_solo2)
+                self._fused2 = _step_program("fused2", _fused2)
+                self._solo2 = _step_program("solo2", _solo2)
 
-            self._fused = jax.jit(_fused)
+            self._fused = _step_program("fused", _fused)
             return
 
         # ---- async closures: sampling fused, token state fed back on device.
@@ -659,8 +679,8 @@ class Engine:
                 state = jnp.where(lastB, state.at[slotB].set(tb[0]), state)
                 return state, ta, tb, staging
 
-            self._fused2 = jax.jit(_fused2_async)
-            self._solo2 = jax.jit(_solo2_async)
+            self._fused2 = _step_program("fused2", _fused2_async)
+            self._solo2 = _step_program("solo2", _solo2_async)
         else:
 
             def _fused_async(params, cache, tok_state, pre_tokens,
@@ -715,11 +735,11 @@ class Engine:
                 state = jnp.where(lastB, state.at[slotB].set(tb[0]), state)
                 return state, ta, tb, cache
 
-            self._fused2 = jax.jit(_fused2_async)
-            self._solo2 = jax.jit(_solo2_async)
+            self._fused2 = _step_program("fused2", _fused2_async)
+            self._solo2 = _step_program("solo2", _solo2_async)
 
-        self._fused = jax.jit(_fused_async)
-        self._solo = jax.jit(_solo_async)
+        self._fused = _step_program("fused", _fused_async)
+        self._solo = _step_program("solo", _solo_async)
 
     # ------------------------------------------------- speculative decoding
     def _init_spec(self) -> None:
@@ -775,7 +795,7 @@ class Engine:
             state = emitted[jnp.arange(emitted.shape[0]), n_accept]
             return state, emitted, n_accept, cache, d_cache
 
-        self._spec_step = jax.jit(spec_core)
+        self._spec_step = _step_program("spec", spec_core)
         if self.schedule != "hybrid":
             return
         if self.cache_kind == "paged":
@@ -814,7 +834,7 @@ class Engine:
                 state = jnp.where(last, state.at[slot].set(pre_tok[0]), state)
                 return state, emitted, n_accept, pre_tok, cache, d_cache
 
-        self._spec_fused = jax.jit(_spec_fused)
+        self._spec_fused = _step_program("spec_fused", _spec_fused)
 
     def _draft_prefill_slot(self, slot: int, tokens: np.ndarray) -> None:
         """Prefill ``tokens`` into the draft cache at ``slot`` so draft
@@ -1186,11 +1206,21 @@ class Engine:
         if len(self._pending) > 1:
             self._observe(self._pending.popleft())
 
+    def _readback(self, step: int, *arrays) -> tuple:
+        """Fetch step ``step``'s device arrays (``None`` passes through)
+        to the host: the blocking device-to-host copy, in an
+        ``Engine.readback`` span (none when there is nothing to fetch)."""
+        if all(a is None for a in arrays):
+            return arrays
+        with self.tracer.phase("Engine.readback", step=step):
+            return jax.device_get(arrays)
+
     def _flush_first(self) -> None:
-        for req, tok in self._first_pending:
+        for req, tok, step in self._first_pending:
+            (tok,) = self._readback(step, tok)
             req.in_flight -= 1
             req.in_flight_steps -= 1
-            req.out_tokens.append(int(np.asarray(tok)[0]))
+            req.out_tokens.append(int(tok[0]))
         self._first_pending.clear()
 
     def _observe(self, rec: _PendingStep) -> None:
@@ -1202,23 +1232,28 @@ class Engine:
         late: the speculative token a later in-flight step sampled for a
         now-done request is masked (``req.done`` short-circuit below)."""
         self._flush_first()
-        if rec.work is not None and rec.work.last:
+        first = rec.work is not None and rec.work.last
+        first2 = rec.work2 is not None and rec.work2.last
+        pre_tok, pre_tok2, toks, eos, n_acc = self._readback(
+            rec.step, rec.pre_tok if first else None,
+            rec.pre_tok2 if first2 else None, rec.tokens, rec.eos,
+            rec.n_accept,
+        )
+        if first:
             req = rec.work.req
             req.in_flight -= 1
             req.in_flight_steps -= 1
-            req.out_tokens.append(int(np.asarray(rec.pre_tok)[0]))
-        if rec.work2 is not None and rec.work2.last:
+            req.out_tokens.append(int(pre_tok[0]))
+        if first2:
             req = rec.work2.req
             req.in_flight -= 1
             req.in_flight_steps -= 1
-            req.out_tokens.append(int(np.asarray(rec.pre_tok2)[0]))
-        if rec.tokens is None:
+            req.out_tokens.append(int(pre_tok2[0]))
+        if toks is None:
             return
-        toks = np.asarray(rec.tokens)
-        if rec.n_accept is not None:
-            self._observe_spec(rec, toks)
+        if n_acc is not None:
+            self._observe_spec(rec, toks, n_acc)
             return
-        eos = np.asarray(rec.eos)
         for i, req in rec.reqs.items():
             req.in_flight -= 1
             req.in_flight_steps -= 1
@@ -1235,13 +1270,13 @@ class Engine:
             ):
                 self._finish(i, req, rec.step)
 
-    def _observe_spec(self, rec: _PendingStep, toks: np.ndarray) -> None:
+    def _observe_spec(self, rec: _PendingStep, toks: np.ndarray,
+                      n_acc: np.ndarray) -> None:
         """Apply one observed speculative window: per batch row, commit
         the accepted drafts plus the bonus/correction token (``toks[i]``
-        holds ``n_accept[i] + 1`` valid leading positions), refund the
+        holds ``n_acc[i] + 1`` valid leading positions), refund the
         unused in-flight charges, and stop at the first finish condition
         — an EOS *inside* the accepted window truncates the rest."""
-        n_acc = np.asarray(rec.n_accept)
         accepted = 0
         for i, req in rec.reqs.items():
             req.in_flight -= rec.charge
@@ -1299,48 +1334,59 @@ class Engine:
             return
         self.stats.victim_drains += 1
         kept = []
-        for r, tok in self._first_pending:
+        for r, tok, step in self._first_pending:
             if r is req:
+                (tok,) = self._readback(step, tok)
                 r.in_flight -= 1
                 r.in_flight_steps -= 1
-                r.out_tokens.append(int(np.asarray(tok)[0]))
+                r.out_tokens.append(int(tok[0]))
             else:
-                kept.append((r, tok))
+                kept.append((r, tok, step))
         self._first_pending[:] = kept
         for rec in self._pending:
-            if rec.work is not None and rec.work.last and rec.work.req is req:
+            first = (rec.work is not None and rec.work.last
+                     and rec.work.req is req)
+            first2 = (rec.work2 is not None and rec.work2.last
+                      and rec.work2.req is req)
+            row = rec.tokens is not None and rec.reqs.get(slot) is req
+            if not (first or first2 or row):
+                continue
+            pre_tok, pre_tok2, toks, eos, n_acc = self._readback(
+                rec.step, rec.pre_tok if first else None,
+                rec.pre_tok2 if first2 else None,
+                *((rec.tokens, rec.eos, rec.n_accept) if row else (None,) * 3),
+            )
+            if first:
                 req.in_flight -= 1
                 req.in_flight_steps -= 1
-                req.out_tokens.append(int(np.asarray(rec.pre_tok)[0]))
+                req.out_tokens.append(int(pre_tok[0]))
                 rec.work = None          # consumed; _observe must not re-apply
-            if rec.work2 is not None and rec.work2.last and rec.work2.req is req:
+            if first2:
                 req.in_flight -= 1
                 req.in_flight_steps -= 1
-                req.out_tokens.append(int(np.asarray(rec.pre_tok2)[0]))
+                req.out_tokens.append(int(pre_tok2[0]))
                 rec.work2 = None
-            if rec.tokens is not None and rec.reqs.get(slot) is req:
+            if row:
                 del rec.reqs[slot]
                 req.in_flight -= rec.charge
                 req.in_flight_steps -= 1
                 if req.done:
                     continue
-                if rec.n_accept is not None:
-                    n_emit = int(np.asarray(rec.n_accept)[slot]) + 1
+                if n_acc is not None:
+                    n_emit = int(n_acc[slot]) + 1
                     self.stats.drafted_tokens += self.spec_depth
                     self.stats.accepted_tokens += n_emit - 1
                     self.stats.spec_accept_samples.append(
                         (n_emit - 1) / self.spec_depth
                     )
-                    self._apply_spec_row(
-                        slot, req, np.asarray(rec.tokens[slot]), n_emit,
-                        rec.step,
-                    )
+                    self._apply_spec_row(slot, req, toks[slot], n_emit,
+                                         rec.step)
                     continue
-                req.out_tokens.append(int(np.asarray(rec.tokens[slot])))
+                req.out_tokens.append(int(toks[slot]))
                 self.stats.generated += 1
                 length = len(req.prompt) + len(req.out_tokens)
                 if (
-                    bool(np.asarray(rec.eos[slot]))
+                    bool(eos[slot])
                     or len(req.out_tokens) >= req.max_new_tokens
                     or length >= self.max_seq - 1
                 ):
@@ -1384,100 +1430,106 @@ class Engine:
         """Whole-prefill step cost, in fixed hybrid-batch units."""
         return max(1, -(-n_tokens // self.prefill_chunk))
 
-    def _admit(self):
+    def _admit(self) -> None:
+        """Whole-prompt admission (decode-only schedule): while a slot and,
+        paged, the blocks allow, prefill the queue head into a slot; a head
+        that does not fit waits (FCFS).  Each admission is scheduled, then
+        dispatched as its own ``prefill`` step program; its prompt blocks
+        and table are pushed in a second ``Engine.schedule`` span."""
+        while True:
+            with self.tracer.phase("Engine.schedule"):
+                admitted = self._admit_next()
+            if admitted is None:
+                return
+            req, slot, full, blocks, n_cached = admitted
+            with self._dispatch_span("prefill"):
+                if self.cache_kind == "paged":
+                    pad = -(-len(full) // self.block_size) * self.block_size
+                    sub_cache = self.model.init_cache(1, pad)
+                else:
+                    sub_cache = self.model.init_cache(1, self.max_seq)
+                logits, sub_cache = self._prefill(
+                    self.params, jnp.asarray(full, jnp.int32)[None], sub_cache
+                )
+            with self.tracer.phase("Engine.schedule"):
+                if self.cache_kind == "paged":
+                    # fill only the blocks the prefix cache didn't already hold
+                    for j in range(n_cached, len(blocks)):
+                        self.cache = paged_dev.write_prompt_block(
+                            self.cache, sub_cache, blocks[j], j * self.block_size
+                        )
+                    self.cache = paged_dev.sync_slot(
+                        self.cache, slot, self.manager.tables[slot], len(full)
+                    )
+                else:
+                    self.cache = kv_cache.insert(self.cache, sub_cache, slot)
+                self.slots[slot] = req
+                self._draft_prefill_slot(slot, full)
+                if self.async_mode:
+                    self._sample_prefill(req, slot, logits)
+            if not self.async_mode:
+                (tok,) = self._sample_host(self.stats.engine_steps, logits)
+                self._sample_prefill(req, slot, int(tok[0]))
+
+    def _admit_next(self):
+        """Take the queue head into the first free slot if it fits: charge
+        its prefill's step cost and record the admission.  Returns
+        ``(req, slot, tokens, blocks, n_cached)`` (paged: the slot's blocks
+        and how many the prefix cache already held), or None.
+
+        A preempted paged request re-enters with its generated tokens
+        folded into the prefill, reproducing its exact decode state."""
+        free = self._free_slots()
+        if not free or not len(self.sched):
+            return None
+        slot = free[0]
+        req = self.sched.peek()
+        blocks, n_cached = None, 0
         if self.cache_kind == "paged":
-            self._admit_paged()
-            return
-        for slot in self._free_slots():
-            if not len(self.sched):
-                break
-            req = self.sched.pop()
-            step0 = self.stats.engine_steps
-            self.stats.engine_steps += self._prefill_cost(len(req.prompt))
-            if req.admit_step < 0:
-                req.admit_step = self.stats.engine_steps
-            self.tracer.on_admit(self.replica, req, step0, slot,
-                                 n_tokens=len(req.prompt))
-            self.tracer.on_chunk(self.replica, req, slot, step0,
-                                 self.stats.engine_steps, 0,
-                                 len(req.prompt), None, True)
-            if self.tracer.enabled:
-                self._trace_prefill_dispatch(len(req.prompt),
-                                             self.stats.engine_steps - step0)
-            prompt = jnp.asarray(req.prompt, jnp.int32)[None]
-            sub_cache = self.model.init_cache(1, self.max_seq)
-            logits, sub_cache = self._prefill(self.params, prompt, sub_cache)
-            self.cache = kv_cache.insert(self.cache, sub_cache, slot)
-            self.slots[slot] = req
-            self._draft_prefill_slot(slot, np.asarray(req.prompt, np.int32))
-            self._sample_prefill(req, slot, logits)
-
-    def _admit_paged(self):
-        """Admit while slots AND blocks allow; head-of-line blocks wait.
-
-        A preempted request re-enters here with its generated tokens
-        folded into the prefill, reproducing its exact decode state.
-        """
-        for slot in self._free_slots():
-            if not len(self.sched):
-                break
-            req = self.sched.peek()
-            full = self._refold(req)
             # the last sampled token is input, not cache content: the KV
             # written at admission covers full[:-1]'s context plus itself,
             # i.e. exactly len(full) positions after prefill
+            full = self._refold(req)
             res = self.manager.try_admit(slot, full)
             if res is None:
-                break                       # out of blocks: wait/FCFS
-            self.sched.pop()
-            step0 = self.stats.engine_steps
-            self.stats.engine_steps += self._prefill_cost(len(full))
-            if req.admit_step < 0:
-                req.admit_step = self.stats.engine_steps
-            self.tracer.on_admit(self.replica, req, step0, slot,
-                                 n_tokens=len(full),
-                                 refold=bool(req.out_tokens))
-            self.tracer.on_chunk(self.replica, req, slot, step0,
-                                 self.stats.engine_steps, 0, len(full),
-                                 None, True)
-            if self.tracer.enabled:
-                self._trace_prefill_dispatch(len(full),
-                                             self.stats.engine_steps - step0)
+                return None                 # out of blocks: wait/FCFS
             blocks, n_cached = res
+        else:
+            full = np.asarray(req.prompt, np.int32)
+        self.sched.pop()
+        step0 = self.stats.engine_steps
+        self.stats.engine_steps += self._prefill_cost(len(full))
+        if req.admit_step < 0:
+            req.admit_step = self.stats.engine_steps
+        self.tracer.on_admit(self.replica, req, step0, slot,
+                             n_tokens=len(full), refold=bool(req.out_tokens))
+        self.tracer.on_chunk(self.replica, req, slot, step0,
+                             self.stats.engine_steps, 0, len(full), None, True)
+        if self.tracer.enabled:
+            self._trace_prefill_dispatch(len(full),
+                                         self.stats.engine_steps - step0)
+        if self.cache_kind == "paged":
             # host-tier prefix hits re-hydrate: apply the copies before
             # the prefill's own block writes go out
             self._apply_pool_directives()
-            pad = -(-len(full) // self.block_size) * self.block_size
-            sub_cache = self.model.init_cache(1, pad)
-            logits, sub_cache = self._prefill(
-                self.params, jnp.asarray(full, jnp.int32)[None], sub_cache
-            )
-            # fill only the blocks the prefix cache didn't already hold
-            for j in range(n_cached, len(blocks)):
-                self.cache = paged_dev.write_prompt_block(
-                    self.cache, sub_cache, blocks[j], j * self.block_size
-                )
-            self.cache = paged_dev.sync_slot(
-                self.cache, slot, self.manager.tables[slot], len(full)
-            )
-            self.slots[slot] = req
-            self._draft_prefill_slot(slot, full)
-            self._sample_prefill(req, slot, logits)
+        return req, slot, full, blocks, n_cached
 
-    def _sample_prefill(self, req: Request, slot: int, logits):
+    def _sample_prefill(self, req: Request, slot: int, first) -> None:
+        """Commit a completed prompt's first token.  Sync mode: ``first``
+        is the token id, sampled and fetched by the caller.  Async: the
+        prompt's logits, sampled on device into ``tok_state`` for the next
+        decode step; the id is fetched lazily with the step stream, so the
+        host never blocks on the prefill here."""
         req.admit_base = len(req.out_tokens)
         if self.async_mode:
-            # sample on device, feed the token into tok_state for the next
-            # decode step, and fetch the id lazily with the step stream —
-            # the host never blocks on the prefill here
-            tok = self._jit_sample(logits, self._step_rng(), cfg=self.sampler)
+            tok = self._jit_sample(first, self._step_rng(), cfg=self.sampler)
             self._tok_state = paged_dev.feed_token(self._tok_state, slot, tok[0])
             self._eos_dev = paged_dev.set_stop_id(self._eos_dev, slot, req.eos_id)
             req.in_flight += 1
             req.in_flight_steps += 1
-            self._first_pending.append((req, tok))
+            self._first_pending.append((req, tok, self.stats.engine_steps))
         else:
-            req.out_tokens.append(int(sample(logits, self._next_rng(), self.sampler)[0]))
+            req.out_tokens.append(first)
         self._record_first_token(req, slot)
 
     def _record_first_token(self, req: Request, slot: int) -> None:
@@ -1523,61 +1575,65 @@ class Engine:
         start = min(len(matched) * bs, (len(full) - 1) // bs * bs)
         return start, len(full)
 
-    def _complete_chunk(self, work: PrefillChunk, pre_logits,
+    def _complete_chunk(self, work: PrefillChunk, first_tok: int | None,
                         advance: bool = True):
-        """Commit an executed chunk (sync mode: host-samples the first
-        token from the chunk's logits when it completes the prompt).
-        ``advance=False`` when the scheduler was already advanced at
-        boundary-packing time (the next prompt had to begin before the
-        fused dispatch was built)."""
-        self.tracer.on_chunk(self.replica, work.req, work.slot,
-                             self.stats.engine_steps - 1,
-                             self.stats.engine_steps, work.start,
-                             work.n_valid, work.bucket, work.last)
-        self._flush_chunk_blocks(work)
-        if advance:
-            self.sched.advance(work)
-        if work.last:
-            req = work.req
-            self.slots[work.slot] = req
-            if self.cache_kind == "paged":
-                self.cache = paged_dev.sync_slot(
-                    self.cache, work.slot, self.manager.tables[work.slot],
-                    work.start + work.n_valid,
-                )
-            self._end_prefill(work.slot)
-            self._sample_prefill(req, work.slot, pre_logits)
+        """Commit an executed chunk (sync mode: ``first_tok`` is the
+        prompt's first token, sampled from the chunk's logits, when the
+        chunk completes the prompt).  ``advance=False`` when the scheduler
+        was already advanced at boundary-packing time (the next prompt had
+        to begin before the fused dispatch was built).  The prompt blocks and
+        table go out in an ``Engine.schedule`` span."""
+        with self.tracer.phase("Engine.schedule"):
+            self.tracer.on_chunk(self.replica, work.req, work.slot,
+                                 self.stats.engine_steps - 1,
+                                 self.stats.engine_steps, work.start,
+                                 work.n_valid, work.bucket, work.last)
+            self._flush_chunk_blocks(work)
+            if advance:
+                self.sched.advance(work)
+            if work.last:
+                req = work.req
+                self.slots[work.slot] = req
+                if self.cache_kind == "paged":
+                    self.cache = paged_dev.sync_slot(
+                        self.cache, work.slot, self.manager.tables[work.slot],
+                        work.start + work.n_valid,
+                    )
+                self._end_prefill(work.slot)
+                self._sample_prefill(req, work.slot, first_tok)
 
     def _complete_chunk_async(self, work: PrefillChunk, advance: bool = True):
         """Async twin of :meth:`_complete_chunk`: the fused step already
         sampled the first token on device and spliced it into
         ``tok_state``; the host only does block/table bookkeeping (safe at
         dispatch time — device data-flow orders it after the step) and
-        records that one more token is in flight."""
-        self.tracer.on_chunk(self.replica, work.req, work.slot,
-                             self.stats.engine_steps - 1,
-                             self.stats.engine_steps, work.start,
-                             work.n_valid, work.bucket, work.last)
-        self._flush_chunk_blocks(work)
-        if advance:
-            self.sched.advance(work)
-        if work.last:
-            req = work.req
-            self.slots[work.slot] = req
-            if self.cache_kind == "paged":
-                self.cache = paged_dev.sync_slot(
-                    self.cache, work.slot, self.manager.tables[work.slot],
-                    work.start + work.n_valid,
+        records that one more token is in flight, in an ``Engine.schedule``
+        span."""
+        with self.tracer.phase("Engine.schedule"):
+            self.tracer.on_chunk(self.replica, work.req, work.slot,
+                                 self.stats.engine_steps - 1,
+                                 self.stats.engine_steps, work.start,
+                                 work.n_valid, work.bucket, work.last)
+            self._flush_chunk_blocks(work)
+            if advance:
+                self.sched.advance(work)
+            if work.last:
+                req = work.req
+                self.slots[work.slot] = req
+                if self.cache_kind == "paged":
+                    self.cache = paged_dev.sync_slot(
+                        self.cache, work.slot, self.manager.tables[work.slot],
+                        work.start + work.n_valid,
+                    )
+                self._draft_prefill_slot(work.slot, self._pf_tokens[work.slot])
+                self._end_prefill(work.slot)
+                req.admit_base = len(req.out_tokens)
+                req.in_flight += 1
+                req.in_flight_steps += 1
+                self._eos_dev = paged_dev.set_stop_id(
+                    self._eos_dev, work.slot, req.eos_id
                 )
-            self._draft_prefill_slot(work.slot, self._pf_tokens[work.slot])
-            self._end_prefill(work.slot)
-            req.admit_base = len(req.out_tokens)
-            req.in_flight += 1
-            req.in_flight_steps += 1
-            self._eos_dev = paged_dev.set_stop_id(
-                self._eos_dev, work.slot, req.eos_id
-            )
-            self._record_first_token(req, work.slot)
+                self._record_first_token(req, work.slot)
 
     def _end_prefill(self, slot: int) -> None:
         """Release a completed prompt's per-slot prefill state (and its
@@ -1785,40 +1841,6 @@ class Engine:
                 return None         # pool dry now: B's chunks run later
         return work2
 
-    def _exec_solo_sync(self, work: PrefillChunk):
-        """Dispatch one chunk through the solo prefill program (sync
-        mode); returns the chunk's logits."""
-        chunk, off, nv = self._chunk_arrays(work)
-        if self.cache_kind == "paged":
-            pre_logits, self.staging = self._solo(
-                self.params, self.staging, chunk,
-                np.int32(self._pf_lane.get(work.slot, 0)), off, nv
-            )
-        else:
-            pre_logits, self.cache = self._solo(
-                self.params, self.cache, chunk, np.int32(work.slot), off, nv
-            )
-        return pre_logits
-
-    def _exec_solo_async(self, work: PrefillChunk, rng):
-        """Async twin of :meth:`_exec_solo_sync`: the solo program samples
-        on device and splices a completed prompt's first token into
-        ``tok_state``; returns the in-flight ``pre_tok`` array."""
-        chunk, off, nv = self._chunk_arrays(work)
-        wslot = np.int32(work.slot)
-        if self.cache_kind == "paged":
-            self._tok_state, pre_tok, self.staging = self._solo(
-                self.params, self.staging, self._tok_state, chunk, wslot,
-                np.int32(self._pf_lane.get(work.slot, 0)), off, nv, rng,
-                work.last,
-            )
-        else:
-            self._tok_state, pre_tok, self.cache = self._solo(
-                self.params, self.cache, self._tok_state,
-                chunk, wslot, off, nv, rng, work.last,
-            )
-        return pre_tok
-
     # ------------------------------------------------------------ telemetry
     def _trace_prefill_dispatch(self, n_tokens: int, n_steps: int) -> StepRecord:
         """StepRecord for a whole-prompt admission prefill (decode-only
@@ -1916,9 +1938,23 @@ class Engine:
                 tokens[i] = req.out_tokens[-1]
         return jnp.asarray(tokens)
 
-    def _finish_decode(self, active: list[int], logits):
-        next_toks = sample(logits, self._next_rng(), self.sampler)
-        next_host = np.asarray(next_toks)
+    def _dispatch_span(self, kind: str):
+        """``Engine.dispatch`` span of the step program about to be
+        enqueued as step ``engine_steps`` (building its inputs included);
+        the enclosing ``Engine.step`` span takes the last one's id."""
+        self._dispatched = (self.stats.engine_steps, kind)
+        return self.tracer.phase("Engine.dispatch",
+                                 step=self.stats.engine_steps, kind=kind)
+
+    def _sample_host(self, step: int, *logits) -> tuple:
+        """Sync mode: sample each logits array (``None`` skips) on the
+        host's rng stream, in order, and fetch the ids."""
+        return self._readback(step, *(
+            None if lg is None else sample(lg, self._next_rng(), self.sampler)
+            for lg in logits
+        ))
+
+    def _finish_decode(self, active: list[int], next_host: np.ndarray):
         for i in active:
             req = self.slots[i]
             tok = int(next_host[i])
@@ -1933,82 +1969,102 @@ class Engine:
                 self._finish(i, req, self.stats.engine_steps)
 
     def step(self) -> bool:
-        """One engine iteration.  Returns whether any work remains."""
-        if self.schedule == "hybrid":
-            if self.async_mode:
-                return self._step_hybrid_async()
-            return self._step_hybrid()
-        if self.async_mode:
-            return self._step_decode_only_async()
-        return self._step_decode_only()
+        """One engine iteration.  Returns whether any work remains.
+
+        Every path emits the same host spans (``Tracer.phase``; nothing
+        without a tracer): ``Engine.step`` around the call, carrying the
+        ``step`` id and ``kind`` of the step program it dispatched, if any;
+        inside it ``Engine.schedule`` (admission, planning, block
+        allocation, and the block-table and prompt-block pushes before the
+        dispatch and, for a finished prompt, after it), ``Engine.dispatch``
+        (inputs built and the program enqueued) and ``Engine.readback``
+        (each blocking device-to-host fetch, with the id of the step
+        fetched)."""
+        with self.tracer.phase("Engine.step") as span:
+            self._dispatched = None
+            if self.schedule == "hybrid":
+                more = (self._step_hybrid_async() if self.async_mode
+                        else self._step_hybrid())
+            elif self.async_mode:
+                more = self._step_decode_only_async()
+            else:
+                more = self._step_decode_only()
+            if self._dispatched is not None:
+                step, kind = self._dispatched
+                span.set_metadata(step=step, kind=kind)
+        return more
 
     def _step_decode_only(self) -> bool:
         self._admit()
-        active = [i for i, s in enumerate(self.slots) if s is not None]
-        if self.cache_kind == "paged" and active:
-            active = self._prepare_append(active)
+        with self.tracer.phase("Engine.schedule"):
+            active = [i for i, s in enumerate(self.slots) if s is not None]
+            if self.cache_kind == "paged" and active:
+                active = self._prepare_append(active)
         if not active:
             return self.sched.has_work()
         self.stats.peak_active = max(self.stats.peak_active, len(active))
+        self.stats.engine_steps += 1
 
         prof = self.profiler
         sampling = prof.enabled and prof.tick()
         if sampling:
             prof.begin(self._profile_fence())
-        logits, self.cache = self._decode(
-            self.params, self.cache, self._decode_tokens()
-        )
+        with self._dispatch_span("decode"):
+            logits, self.cache = self._decode(
+                self.params, self.cache, self._decode_tokens()
+            )
         if sampling:
             prof.end(self._profile_fence())
         self.stats.decode_steps += 1
-        self.stats.engine_steps += 1
         if self._telemetry:
             rec = self._trace_step("decode", active)
             if sampling:
                 prof.commit(rec)
-        self._finish_decode(active, logits)
+        (toks,) = self._sample_host(self.stats.engine_steps, logits)
+        self._finish_decode(active, toks)
         return any(s is not None for s in self.slots) or self.sched.has_work()
 
     def _step_decode_only_async(self) -> bool:
         self._admit()
-        active = self._predicted_active()
-        if self.cache_kind == "paged" and active:
-            active = self._prepare_append(active)
+        with self.tracer.phase("Engine.schedule"):
+            active = self._predicted_active()
+            if self.cache_kind == "paged" and active:
+                active = self._prepare_append(active)
         if not active:
             self._drain()               # nothing to dispatch: settle state
             return any(s is not None for s in self.slots) or self.sched.has_work()
         self.stats.peak_active = max(self.stats.peak_active, len(active))
+        self.stats.engine_steps += 1
+        kind = "spec" if self.spec_depth else "decode"
 
         prof = self.profiler
         sampling = prof.enabled and prof.tick()
         if sampling:
             prof.begin(self._profile_fence())    # settle in-flight steps
         eos = n_accept = None
-        if self.spec_depth:
-            (self._tok_state, toks, n_accept,
-             self.cache, self.d_cache) = self._spec_step(
-                self.params, self.draft_params, self.cache, self.d_cache,
-                self._tok_state, self._step_rng(),
-            )
-        else:
-            toks, eos, self.cache = self._decode_sampled(
-                self.params, self.cache, self._tok_state, self._step_rng(),
-                self._eos_dev, sampler=self.sampler,
-            )
-            self._tok_state = toks
+        with self._dispatch_span(kind):
+            if self.spec_depth:
+                (self._tok_state, toks, n_accept,
+                 self.cache, self.d_cache) = self._spec_step(
+                    self.params, self.draft_params, self.cache, self.d_cache,
+                    self._tok_state, self._step_rng(),
+                )
+            else:
+                toks, eos, self.cache = self._decode_sampled(
+                    self.params, self.cache, self._tok_state, self._step_rng(),
+                    self._eos_dev, sampler=self.sampler,
+                )
+                self._tok_state = toks
         if sampling:
             prof.end(self._profile_fence())
         self.stats.decode_steps += 1
-        self.stats.engine_steps += 1
         charge = 1
         if self.spec_depth:
             charge = self.spec_depth + 1
             self.stats.spec_steps += 1
             self.stats.draft_steps += self.spec_depth + 1
         if self._telemetry:
-            rec = self._trace_step(
-                "spec" if self.spec_depth else "decode", active
-            )
+            rec = self._trace_step(kind, active)
             if sampling:
                 prof.commit(rec)
             if self.spec_depth:
@@ -2028,135 +2084,16 @@ class Engine:
         ))
         return True
 
-    def _step_hybrid(self) -> bool:
-        sched = self.sched
-        if sched.inflight is None and len(sched):
-            free = self._free_slots()
-            if free:
-                req = sched.pop()
-                slot = free[0]
-                start, total = self._begin_prefill(req, slot)
-                sched.begin(req, slot, start, total)
-                if req.admit_step < 0:
-                    req.admit_step = self.stats.engine_steps + 1
-                self.tracer.on_admit(self.replica, req,
-                                     self.stats.engine_steps, slot,
-                                     n_tokens=total,
-                                     refold=bool(req.out_tokens))
-
-        active = [i for i, s in enumerate(self.slots) if s is not None]
-        if self.cache_kind == "paged" and active:
-            active = self._prepare_append(active)
-        decision = sched.schedule(active)
-        active = decision.decode_slots       # the scheduler owns the batch
-        work = decision.prefill
-        if work is not None and self.cache_kind == "paged":
-            ok = self.manager.extend_chunked(
-                work.slot, len(self._pf_tokens[work.slot]),
-                work.start + work.n_valid, work.last,
-            )
-            if not ok:
-                work = None             # pool dry: decode-only iteration
-        if not active and work is None:
-            return sched.has_work()
-
-        self.stats.engine_steps += 1
-        self.stats.peak_active = max(self.stats.peak_active, len(active))
-
-        # Sarathi-SC boundary packing: when `work` finishes its prompt,
-        # the next prompt begins *now* and its head chunk joins the same
-        # dispatch, filling the budget the small final chunk left unused.
-        # A's chunk arrays are built before _begin_prefill pins B.
-        work2 = None
-        pre_advanced = False
-        if work is not None:
-            chunk, off, nv = self._chunk_arrays(work)
-            if work.last and len(sched):
-                sched.advance(work)     # A rides this dispatch regardless
-                pre_advanced = True
-                work2 = self._boundary_chunk(
-                    sched.token_budget - len(active) - work.n_valid, work.slot
-                )
-                if work2 is not None:
-                    chunk2, off2, nv2 = self._chunk_arrays(work2)
-
-        prof = self.profiler
-        sampling = prof.enabled and prof.tick()
-        if sampling:
-            prof.begin(self._profile_fence())
-        dec_logits = pre_logits = logits2 = None
-        if work2 is not None:
-            self.stats.boundary_packs += 1
-            self.tracer.on_boundary_pack(self.replica, work2.req,
-                                         self.stats.engine_steps, work2.slot)
-            if self.cache_kind == "paged":
-                laneA = np.int32(self._pf_lane.get(work.slot, 0))
-                laneB = np.int32(self._pf_lane.get(work2.slot, 0))
-                if active:
-                    (dec_logits, pre_logits, logits2,
-                     self.cache, self.staging) = self._fused2(
-                        self.params, self.cache, self.staging,
-                        self._decode_tokens(),
-                        chunk, laneA, off, nv, chunk2, laneB, off2, nv2,
-                    )
-                    self.stats.decode_steps += 1
-                else:
-                    pre_logits, logits2, self.staging = self._solo2(
-                        self.params, self.staging,
-                        chunk, laneA, off, nv, chunk2, laneB, off2, nv2,
-                    )
-            elif active:
-                dec_logits, pre_logits, logits2, self.cache = self._fused2(
-                    self.params, self.cache, self._decode_tokens(),
-                    chunk, np.int32(work.slot), off, nv,
-                    chunk2, np.int32(work2.slot), off2, nv2,
-                )
-                self.stats.decode_steps += 1
-            else:
-                pre_logits, logits2, self.cache = self._solo2(
-                    self.params, self.cache,
-                    chunk, np.int32(work.slot), off, nv,
-                    chunk2, np.int32(work2.slot), off2, nv2,
-                )
-        elif active and work is not None:
-            if self.cache_kind == "paged":
-                dec_logits, pre_logits, self.cache, self.staging = self._fused(
-                    self.params, self.cache, self.staging,
-                    self._decode_tokens(), chunk,
-                    np.int32(self._pf_lane.get(work.slot, 0)), off, nv,
-                )
-            else:
-                dec_logits, pre_logits, self.cache = self._fused(
-                    self.params, self.cache, self._decode_tokens(), chunk,
-                    np.int32(work.slot), off, nv,
-                )
-            self.stats.decode_steps += 1
-        elif active:
-            dec_logits, self.cache = self._decode(
-                self.params, self.cache, self._decode_tokens()
-            )
-            self.stats.decode_steps += 1
-        else:
-            pre_logits = self._exec_solo_sync(work)
-
-        if sampling:
-            prof.end(self._profile_fence())
-        if self._telemetry:
-            rec = self._trace_step(self._dispatch_kind(active, work, work2),
-                                   active, work, work2)
-            if sampling:
-                prof.commit(rec)
-        if active:
-            self._finish_decode(active, dec_logits)
-        if work is not None:
-            self.stats.prefill_chunks += 1
-            self._complete_chunk(work, pre_logits, advance=not pre_advanced)
-        if work2 is not None:
-            self.stats.prefill_chunks += 1
-            self._complete_chunk(work2, logits2)
-        return any(s is not None for s in self.slots) or sched.has_work()
-
-    def _step_hybrid_async(self) -> bool:
+    def _schedule_hybrid(self):
+        """Plan one hybrid iteration (both execution modes): admit the
+        queue head into chunked prefill, settle the decode batch's blocks,
+        pack the token budget, allocate the chunk's blocks, and, when the
+        chunk finishes its prompt, begin the next one and pack its head
+        chunk into the same dispatch (Sarathi-SC boundary packing; not
+        under speculation — the fused2 programs have no spec variant, and
+        the budget a verify leaves over rarely fits two chunks).  Returns
+        ``(active, work, work2, pre_advanced)``, with the step counted, or
+        None when there is nothing to dispatch."""
         sched = self.sched
         if sched.inflight is None and len(sched):
             free = self._free_slots()
@@ -2175,7 +2112,8 @@ class Engine:
         active = self._predicted_active()
         if self.cache_kind == "paged" and active:
             active = self._prepare_append(active)
-        decision = sched.plan_ahead(active)
+        decision = (sched.plan_ahead if self.async_mode
+                    else sched.schedule)(active)
         active = decision.decode_slots       # the scheduler owns the batch
         work = decision.prefill
         if work is not None and self.cache_kind == "paged":
@@ -2186,45 +2124,150 @@ class Engine:
             if not ok:
                 work = None             # pool dry: decode-only iteration
         if not active and work is None:
-            self._drain()
-            return any(s is not None for s in self.slots) or sched.has_work()
+            return None
 
         self.stats.engine_steps += 1
         self.stats.peak_active = max(self.stats.peak_active, len(active))
-        rng = self._step_rng()
-
-        # boundary packing, async twin (see _step_hybrid): the next
-        # prompt's head chunk joins the same sampled dispatch.  Disabled
-        # under speculation — the fused2 programs have no spec variant,
-        # and the budget a spec verify leaves over rarely fits two chunks
         work2 = None
         pre_advanced = False
-        if work is not None:
-            chunk, off, nv = self._chunk_arrays(work)
-            wslot = np.int32(work.slot)
-            lane = np.int32(self._pf_lane.get(work.slot, 0))
-            if work.last and len(sched) and not self.spec_depth:
-                sched.advance(work)
-                pre_advanced = True
-                work2 = self._boundary_chunk(
-                    sched.token_budget - len(active) - work.n_valid, work.slot
+        if work is not None and work.last and len(sched) and not self.spec_depth:
+            sched.advance(work)         # A rides this dispatch regardless
+            pre_advanced = True
+            work2 = self._boundary_chunk(
+                sched.token_budget - len(active) - work.n_valid, work.slot
+            )
+        return active, work, work2, pre_advanced
+
+    def _step_hybrid(self) -> bool:
+        with self.tracer.phase("Engine.schedule"):
+            plan = self._schedule_hybrid()
+        if plan is None:
+            return self.sched.has_work()
+        active, work, work2, pre_advanced = plan
+        if work2 is not None:
+            self.stats.boundary_packs += 1
+            self.tracer.on_boundary_pack(self.replica, work2.req,
+                                         self.stats.engine_steps, work2.slot)
+        kind = self._dispatch_kind(active, work, work2)
+
+        prof = self.profiler
+        sampling = prof.enabled and prof.tick()
+        if sampling:
+            prof.begin(self._profile_fence())
+        dec_logits = pre_logits = logits2 = None
+        paged = self.cache_kind == "paged"
+        with self._dispatch_span(kind):
+            if work is not None:
+                chunk, off, nv = self._chunk_arrays(work)
+                # the paged cache stages chunks in a lane, the dense one
+                # writes them at the slot
+                where = np.int32(self._pf_lane.get(work.slot, 0) if paged
+                                 else work.slot)
+            if work2 is not None:
+                chunk2, off2, nv2 = self._chunk_arrays(work2)
+                where2 = np.int32(self._pf_lane.get(work2.slot, 0) if paged
+                                  else work2.slot)
+                if paged and active:
+                    (dec_logits, pre_logits, logits2,
+                     self.cache, self.staging) = self._fused2(
+                        self.params, self.cache, self.staging,
+                        self._decode_tokens(),
+                        chunk, where, off, nv, chunk2, where2, off2, nv2,
+                    )
+                elif paged:
+                    pre_logits, logits2, self.staging = self._solo2(
+                        self.params, self.staging,
+                        chunk, where, off, nv, chunk2, where2, off2, nv2,
+                    )
+                elif active:
+                    dec_logits, pre_logits, logits2, self.cache = self._fused2(
+                        self.params, self.cache, self._decode_tokens(),
+                        chunk, where, off, nv, chunk2, where2, off2, nv2,
+                    )
+                else:
+                    pre_logits, logits2, self.cache = self._solo2(
+                        self.params, self.cache,
+                        chunk, where, off, nv, chunk2, where2, off2, nv2,
+                    )
+            elif active and work is not None:
+                if paged:
+                    dec_logits, pre_logits, self.cache, self.staging = self._fused(
+                        self.params, self.cache, self.staging,
+                        self._decode_tokens(), chunk, where, off, nv,
+                    )
+                else:
+                    dec_logits, pre_logits, self.cache = self._fused(
+                        self.params, self.cache, self._decode_tokens(), chunk,
+                        where, off, nv,
+                    )
+            elif active:
+                dec_logits, self.cache = self._decode(
+                    self.params, self.cache, self._decode_tokens()
                 )
-                if work2 is not None:
-                    chunk2, off2, nv2 = self._chunk_arrays(work2)
-                    wslot2 = np.int32(work2.slot)
-                    lane2 = np.int32(self._pf_lane.get(work2.slot, 0))
+            elif paged:
+                pre_logits, self.staging = self._solo(
+                    self.params, self.staging, chunk, where, off, nv
+                )
+            else:
+                pre_logits, self.cache = self._solo(
+                    self.params, self.cache, chunk, where, off, nv
+                )
+        if active:
+            self.stats.decode_steps += 1
+
+        if sampling:
+            prof.end(self._profile_fence())
+        if self._telemetry:
+            rec = self._trace_step(kind, active, work, work2)
+            if sampling:
+                prof.commit(rec)
+        dec, first, first2 = self._sample_host(
+            self.stats.engine_steps, dec_logits,
+            pre_logits if work is not None and work.last else None,
+            logits2 if work2 is not None and work2.last else None,
+        )
+        if active:
+            self._finish_decode(active, dec)
+        if work is not None:
+            self.stats.prefill_chunks += 1
+            self._complete_chunk(work, None if first is None else int(first[0]),
+                                 advance=not pre_advanced)
+        if work2 is not None:
+            self.stats.prefill_chunks += 1
+            self._complete_chunk(work2,
+                                 None if first2 is None else int(first2[0]))
+        return any(s is not None for s in self.slots) or self.sched.has_work()
+
+    def _step_hybrid_async(self) -> bool:
+        with self.tracer.phase("Engine.schedule"):
+            plan = self._schedule_hybrid()
+        if plan is None:
+            self._drain()
+            return any(s is not None for s in self.slots) or self.sched.has_work()
+        active, work, work2, pre_advanced = plan
+        rng = self._step_rng()
+        if work2 is not None:
+            self.stats.boundary_packs += 1
+            self.tracer.on_boundary_pack(self.replica, work2.req,
+                                         self.stats.engine_steps, work2.slot)
+        kind = self._dispatch_kind(active, work, work2)
+        paged = self.cache_kind == "paged"
 
         prof = self.profiler
         sampling = prof.enabled and prof.tick()
         if sampling:
             prof.begin(self._profile_fence())    # settle in-flight steps
         toks = eos = pre_tok = pre_tok2 = n_accept = None
-        if work2 is not None:
-            self.stats.boundary_packs += 1
-            self.tracer.on_boundary_pack(self.replica, work2.req,
-                                         self.stats.engine_steps, work2.slot)
-            if self.cache_kind == "paged":
-                if active:
+        with self._dispatch_span(kind):
+            if work is not None:
+                chunk, off, nv = self._chunk_arrays(work)
+                wslot = np.int32(work.slot)
+                lane = np.int32(self._pf_lane.get(work.slot, 0))
+            if work2 is not None:
+                chunk2, off2, nv2 = self._chunk_arrays(work2)
+                wslot2 = np.int32(work2.slot)
+                lane2 = np.int32(self._pf_lane.get(work2.slot, 0))
+                if paged and active:
                     (self._tok_state, toks, eos, pre_tok, pre_tok2,
                      self.cache, self.staging) = self._fused2(
                         self.params, self.cache, self.staging, self._tok_state,
@@ -2232,8 +2275,7 @@ class Engine:
                         chunk2, wslot2, lane2, off2, nv2,
                         rng, self._eos_dev, work2.last,
                     )
-                    self.stats.decode_steps += 1
-                else:
+                elif paged:
                     (self._tok_state, pre_tok, pre_tok2,
                      self.staging) = self._solo2(
                         self.params, self.staging, self._tok_state,
@@ -2241,64 +2283,71 @@ class Engine:
                         chunk2, wslot2, lane2, off2, nv2,
                         rng, work2.last,
                     )
-            elif active:
-                (self._tok_state, toks, eos, pre_tok, pre_tok2,
-                 self.cache) = self._fused2(
-                    self.params, self.cache, self._tok_state,
-                    chunk, wslot, off, nv, chunk2, wslot2, off2, nv2,
-                    rng, self._eos_dev, work2.last,
-                )
-                self.stats.decode_steps += 1
-            else:
-                self._tok_state, pre_tok, pre_tok2, self.cache = self._solo2(
-                    self.params, self.cache, self._tok_state,
-                    chunk, wslot, off, nv, chunk2, wslot2, off2, nv2,
-                    rng, work2.last,
-                )
-        elif active and work is not None:
-            if self.spec_depth:
-                if self.cache_kind == "paged":
+                elif active:
+                    (self._tok_state, toks, eos, pre_tok, pre_tok2,
+                     self.cache) = self._fused2(
+                        self.params, self.cache, self._tok_state,
+                        chunk, wslot, off, nv, chunk2, wslot2, off2, nv2,
+                        rng, self._eos_dev, work2.last,
+                    )
+                else:
+                    self._tok_state, pre_tok, pre_tok2, self.cache = self._solo2(
+                        self.params, self.cache, self._tok_state,
+                        chunk, wslot, off, nv, chunk2, wslot2, off2, nv2,
+                        rng, work2.last,
+                    )
+            elif active and work is not None:
+                if self.spec_depth and paged:
                     (self._tok_state, toks, n_accept, pre_tok, self.cache,
                      self.staging, self.d_cache) = self._spec_fused(
                         self.params, self.draft_params, self.cache,
                         self.staging, self.d_cache, self._tok_state,
                         chunk, wslot, lane, off, nv, rng, work.last,
                     )
-                else:
+                elif self.spec_depth:
                     (self._tok_state, toks, n_accept, pre_tok,
                      self.cache, self.d_cache) = self._spec_fused(
                         self.params, self.draft_params, self.cache,
                         self.d_cache, self._tok_state,
                         chunk, wslot, off, nv, rng, work.last,
                     )
-            elif self.cache_kind == "paged":
-                (self._tok_state, toks, eos, pre_tok,
-                 self.cache, self.staging) = self._fused(
-                    self.params, self.cache, self.staging, self._tok_state,
-                    chunk, wslot, lane, off, nv, rng, self._eos_dev, work.last,
+                elif paged:
+                    (self._tok_state, toks, eos, pre_tok,
+                     self.cache, self.staging) = self._fused(
+                        self.params, self.cache, self.staging, self._tok_state,
+                        chunk, wslot, lane, off, nv, rng, self._eos_dev,
+                        work.last,
+                    )
+                else:
+                    self._tok_state, toks, eos, pre_tok, self.cache = self._fused(
+                        self.params, self.cache, self._tok_state,
+                        chunk, wslot, off, nv, rng, self._eos_dev, work.last,
+                    )
+            elif active:
+                if self.spec_depth:
+                    (self._tok_state, toks, n_accept,
+                     self.cache, self.d_cache) = self._spec_step(
+                        self.params, self.draft_params, self.cache,
+                        self.d_cache, self._tok_state, rng,
+                    )
+                else:
+                    toks, eos, self.cache = self._decode_sampled(
+                        self.params, self.cache, self._tok_state, rng,
+                        self._eos_dev, sampler=self.sampler,
+                    )
+                    self._tok_state = toks
+            elif paged:
+                self._tok_state, pre_tok, self.staging = self._solo(
+                    self.params, self.staging, self._tok_state, chunk, wslot,
+                    lane, off, nv, rng, work.last,
                 )
             else:
-                self._tok_state, toks, eos, pre_tok, self.cache = self._fused(
+                self._tok_state, pre_tok, self.cache = self._solo(
                     self.params, self.cache, self._tok_state,
-                    chunk, wslot, off, nv, rng, self._eos_dev, work.last,
+                    chunk, wslot, off, nv, rng, work.last,
                 )
+        if active:
             self.stats.decode_steps += 1
-        elif active:
-            if self.spec_depth:
-                (self._tok_state, toks, n_accept,
-                 self.cache, self.d_cache) = self._spec_step(
-                    self.params, self.draft_params, self.cache, self.d_cache,
-                    self._tok_state, rng,
-                )
-            else:
-                toks, eos, self.cache = self._decode_sampled(
-                    self.params, self.cache, self._tok_state, rng,
-                    self._eos_dev, sampler=self.sampler,
-                )
-                self._tok_state = toks
-            self.stats.decode_steps += 1
-        else:
-            pre_tok = self._exec_solo_async(work, rng)
 
         if sampling:
             prof.end(self._profile_fence())
@@ -2314,8 +2363,7 @@ class Engine:
                 )
 
         if self._telemetry:
-            srec = self._trace_step(self._dispatch_kind(active, work, work2),
-                                    active, work, work2)
+            srec = self._trace_step(kind, active, work, work2)
             if sampling:
                 prof.commit(srec)
         reqs = {}

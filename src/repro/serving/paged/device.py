@@ -28,6 +28,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Pytree = Any
 
@@ -293,14 +294,21 @@ def set_stop_id(eos_ids: jax.Array, slot: int, eos_id: int) -> jax.Array:
     return _set_scalar(eos_ids, slot, jnp.int32(eos_id))
 
 
+def _row_snapshot(row) -> jax.Array:
+    """A host table row as it is now.  On the CPU the device may alias an
+    aligned host buffer and read it only when the queued push runs, after
+    the manager has rewritten the row in place (a freed slot's zeros
+    replaced by its next request's blocks), so the copy is made here, on
+    the host."""
+    return jnp.asarray(np.array(row, np.int32))
+
+
 def sync_slot(cache: Pytree, slot: int, row, length: int | None = None) -> Pytree:
     """Push one host block-table row (and optionally the slot length) to
     the device cache."""
     out = {
         **cache,
-        "block_tables": _set_row(
-            cache["block_tables"], slot, jnp.asarray(row, jnp.int32)
-        ),
+        "block_tables": _set_row(cache["block_tables"], slot, _row_snapshot(row)),
     }
     if length is not None:
         out["lengths"] = out["lengths"].at[slot].set(jnp.int32(length))
@@ -312,9 +320,7 @@ def sync_host_slot(cache: Pytree, slot: int, row, cold_len: int) -> Pytree:
     hot attention window's start) to the device cache."""
     out = {
         **cache,
-        "host_tables": _set_row(
-            cache["host_tables"], slot, jnp.asarray(row, jnp.int32)
-        ),
+        "host_tables": _set_row(cache["host_tables"], slot, _row_snapshot(row)),
     }
     out["cold_lengths"] = _set_scalar(out["cold_lengths"], slot, jnp.int32(cold_len))
     return out

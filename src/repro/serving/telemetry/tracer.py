@@ -26,11 +26,12 @@ first-token/finish observations to an
 ``route`` events (policy, chosen replica, spill).
 
 Async dispatch-ahead engines close spans at *observe* time, one step
-after the dispatch that produced the tokens.  Observe-time closes
-therefore carry two wall stamps when ``wall=True``: the close's own
-``t_end`` and a ``wall_dispatch`` attr looked up from the step's
-dispatch record — viewers can reconstruct the true device overlap from
-the pair.
+after the dispatch that produced the tokens.  Where a step ran on the
+device is read from the profiler's own trace instead: ``phase`` opens a
+``jax.profiler.TraceAnnotation`` (``Engine.step``, ``Engine.schedule``,
+``Engine.dispatch``, ``Engine.readback``, with the step id and kind as
+attributes), which lands on the same clock as the device's programs
+(``jit_step_<kind>``) whenever a profiler trace is being captured.
 
 Tracks: spans carry a ``(replica, track)`` address — ``track`` is the
 engine slot the work ran on, or one of the reserved tracks
@@ -56,6 +57,8 @@ from __future__ import annotations
 import dataclasses
 import time
 from typing import Any
+
+import jax
 
 # reserved track ids (engine slots occupy 0..n_slots-1)
 TRACK_QUEUE = 1000
@@ -169,7 +172,25 @@ class NullTracer:
     def wall(self):
         return None
 
+    def phase(self, name, **attrs):
+        return _NO_SPAN
 
+
+class _NoSpan:
+    """The span a disabled tracer hands out: one shared instance, so an
+    untraced engine step emits nothing and allocates no span."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **attrs):
+        pass
+
+
+_NO_SPAN = _NoSpan()
 NULL_TRACER = NullTracer()
 
 
@@ -194,16 +215,16 @@ class Tracer:
         self.steps: list = []                   # StepRecord, append order
         self.requests: dict[tuple[int, int], _RequestState] = {}
         self.round = 0                          # cluster round (set by Cluster)
-        # (replica, step) -> wall stamp of that step's *dispatch*, so
-        # observe-time closes (async lands them a step later) can carry
-        # both stamps and trace viewers see the true overlap
-        self._step_wall: dict[tuple[int, int], float] = {}
 
     def wall(self) -> float | None:
         return time.perf_counter() if self.use_wall else None
 
-    def _dispatch_wall(self, replica: int, step: int) -> float | None:
-        return self._step_wall.get((replica, step)) if self.use_wall else None
+    def phase(self, name: str, **attrs) -> jax.profiler.TraceAnnotation:
+        """A host span of the engine's own phases in the profiler's trace
+        (recorded only while one is captured), on the device's clock;
+        ``attrs`` become the event's stats, and ``set_metadata`` on the
+        open span adds more."""
+        return jax.profiler.TraceAnnotation(name, **attrs)
 
     # ------------------------------------------------------ request lifecycle
     def _state(self, replica: int, req) -> _RequestState:
@@ -250,9 +271,6 @@ class Tracer:
         chunk covering its ceil(L/prefill_chunk)-step cost)."""
         attrs = {"pos": pos, "n_valid": n_valid, "bucket": bucket,
                  "last": last}
-        wd = self._dispatch_wall(replica, end_step)
-        if wd is not None:
-            attrs["wall_dispatch"] = wd
         self.spans.append(Span(
             replica=replica, track=slot, uid=req.uid, name="prefill_chunk",
             start=start_step, end=end_step, t_end=self.wall(), attrs=attrs,
@@ -275,29 +293,18 @@ class Tracer:
                                 target=self.slo.ttft_target)
         st.decode = Span(replica=replica, track=slot, uid=req.uid,
                          name="decode", start=step, t_start=self.wall())
-        wd = self._dispatch_wall(replica, step)
-        if wd is not None:
-            st.decode.attrs["wall_dispatch"] = wd
         self.spans.append(st.decode)
 
     def on_finish(self, replica: int, req, step: int, slot: int) -> None:
         st = self._state(replica, req)
-        wd = self._dispatch_wall(replica, step)
         if st.decode is not None and not st.decode.closed:
             st.decode.end = step
             st.decode.t_end = self.wall()
             st.decode.attrs["generated"] = len(req.out_tokens)
-            if wd is not None:
-                # async closes land at observe time, one step after the
-                # dispatch that produced the final token: record both
-                # stamps so viewers can show the true device overlap
-                st.decode.attrs["wall_dispatch"] = wd
         st.decode = None
         st.finished = True
-        attrs = {"generated": len(req.out_tokens)}
-        if wd is not None:
-            attrs["wall_dispatch"] = wd
-        self._event(replica, slot, req.uid, "finish", step, **attrs)
+        self._event(replica, slot, req.uid, "finish", step,
+                    generated=len(req.out_tokens))
         if self.slo is not None:
             gen = len(req.out_tokens)
             first_step = getattr(req, "first_token_step", -1)
@@ -316,9 +323,6 @@ class Tracer:
             st.decode.end = step
             st.decode.t_end = self.wall()
             st.decode.attrs["preempted"] = True
-            wd = self._dispatch_wall(replica, step)
-            if wd is not None:
-                st.decode.attrs["wall_dispatch"] = wd
         st.decode = None
         self._event(replica, slot, req.uid, "preempted", step, slot=slot)
         st.queued = Span(replica=replica, track=TRACK_QUEUE, uid=req.uid,
@@ -367,8 +371,6 @@ class Tracer:
         """Append one per-dispatch StepRecord (built by the engine only
         when ``enabled`` — see ``Engine._trace_step``)."""
         self.steps.append(record)
-        if record.wall is not None:
-            self._step_wall[(record.replica, record.step)] = record.wall
 
     # --------------------------------------------------------------- router
     def on_route(self, uid: int, replica: int, policy: str, rank_pos: int,

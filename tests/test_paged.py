@@ -241,3 +241,36 @@ def test_paged_engine_admission_gated_on_blocks():
     dense, _, _ = _serve(model, params, prompts, 4, cache_kind="dense")
     for a, b in zip(dense, paged):
         assert b.done and a.out_tokens == b.out_tokens
+
+
+@pytest.mark.parametrize("table", ["block_tables", "host_tables"])
+def test_table_push_sends_the_row_as_it_was(table):
+    """A queued table push carries the host row of its call: the manager
+    rewrites rows in place (a freed slot's zeros, then its next request's
+    blocks) before the device runs the push.  The push here waits on a
+    slow program, and the row sits in a 64-byte-aligned buffer that the
+    CPU device could otherwise alias."""
+    from repro.serving.paged import device as paged_dev
+
+    @jax.jit
+    def slow_zeros(a):
+        a = jax.lax.fori_loop(0, 30, lambda i, a: jnp.tanh(a @ a), a)
+        return jnp.zeros((4, 16), jnp.int32) + (a[0, 0] * 0).astype(jnp.int32)
+
+    def push(row):
+        slow = jnp.full((384, 384), 1e-3, jnp.float32)
+        cache = {"block_tables": slow_zeros(slow),
+                 "host_tables": slow_zeros(slow),
+                 "cold_lengths": jnp.zeros((4,), jnp.int32)}
+        if table == "block_tables":
+            return paged_dev.sync_slot(cache, 1, row)
+        return paged_dev.sync_host_slot(cache, 1, row, 0)
+
+    raw = np.zeros(4 * 16 * 4 + 64, np.uint8)
+    off = -raw.ctypes.data % 64
+    rows = raw[off:off + 4 * 16 * 4].view(np.int32).reshape(4, 16)
+    jax.block_until_ready(push(rows[1]))        # compiled before the race
+    rows[1] = 7
+    out = push(rows[1])
+    rows[1] = 9                     # the slot's next request, in place
+    assert np.asarray(out[table])[1].tolist() == [7] * 16

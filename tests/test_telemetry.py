@@ -3,6 +3,7 @@ combo (sync and async, incl. preemption/refold and boundary packing),
 Perfetto trace export round-trips and validates, the metrics registry
 matches legacy ``EngineStats`` exactly, tracing never changes tokens,
 and the disabled tracer stays a no-op."""
+import collections
 import json
 
 import jax
@@ -348,3 +349,98 @@ def test_step_records_cover_composition(model_params):
     decode = [r for r in tracer.steps if r.kind == "decode"]
     if fused and decode:
         assert max(f.oi for f in fused) > min(d.oi for d in decode)
+
+
+# ------------------------------------------ phase spans on the device clock
+def _phase(events, name):
+    return [e for e in events if e.name == name]
+
+
+@pytest.mark.parametrize("combo", COMBOS,
+                         ids=["dense-decode", "dense-hybrid",
+                              "paged-decode", "paged-hybrid"])
+@pytest.mark.parametrize("async_mode", [False, True], ids=["sync", "async"])
+def test_engine_phase_spans(model_params, profiled, combo, async_mode):
+    """Every step path emits the same phase spans: one ``Engine.step`` per
+    call; ``Engine.schedule`` before each ``Engine.dispatch``, whose step
+    id and kind are the matching ``StepRecord``'s; every prompt-block
+    write inside an ``Engine.schedule``; every step whose program returns
+    tokens read back once, outside any dispatch."""
+    model, params = model_params
+
+    def serve():
+        tracer = Tracer()
+        eng = Engine(model, params, tracer=tracer, n_slots=2, max_seq=32,
+                     async_mode=async_mode, **combo)
+        for i, p in enumerate(PROMPTS):
+            eng.submit(Request(uid=i, prompt=p, max_new_tokens=5))
+        calls = 1
+        while eng.step():
+            calls += 1
+        return eng, tracer, calls
+
+    (eng, tracer, calls), events = profiled(serve)
+    assert not eng._pending and eng.stats.victim_drains == 0
+    steps = _phase(events, "Engine.step")
+    schedules = _phase(events, "Engine.schedule")
+    dispatches = _phase(events, "Engine.dispatch")
+    readbacks = _phase(events, "Engine.readback")
+    assert len(steps) == calls
+    assert collections.Counter((d.stats["step"], d.stats["kind"]) for d in dispatches) \
+        == collections.Counter((r.step, r.kind) for r in tracer.steps)
+    for st in steps:
+        inner = [d for d in dispatches if d.inside(st)]
+        if not inner:
+            assert "step" not in st.stats
+            continue
+        assert (st.stats["step"], st.stats["kind"]) == \
+            (inner[-1].stats["step"], inner[-1].stats["kind"])
+        assert any(s.inside(st) and s.end <= inner[0].start
+                   for s in schedules)
+    for e in schedules + dispatches + readbacks:
+        assert sum(e.inside(st) for st in steps) == 1, e
+    for r in readbacks:
+        assert not any(r.inside(d) for d in dispatches), r
+    # prompt blocks go to the pool in the schedule phase, whether pushed
+    # before the dispatch or, for a finished prompt, after it
+    writes = _phase(events, "PjitFunction(_write_block)")
+    assert bool(writes) == (combo.get("cache_kind") == "paged")
+    for w in writes:
+        assert any(w.inside(s) for s in schedules), w
+    fetched = {r.step for r in tracer.steps
+               if r.decode_batch > 0 or r.kind == "prefill"}
+    fetched |= {s.end for s in tracer.spans
+                if s.name == "prefill_chunk" and s.attrs["last"]}
+    counts = collections.Counter(r.stats["step"] for r in readbacks)
+    assert set(counts) == fetched
+    assert set(counts.values()) == {1}
+
+
+def test_untraced_engine_records_no_phase_spans(model_params, profiled):
+    model, params = model_params
+    (reqs, _, _), events = profiled(lambda: _serve_traced(
+        model, params, PROMPTS, tracer=NULL_TRACER, cache_kind="paged",
+        block_size=8, schedule="hybrid", prefill_chunk=8))
+    assert all(r.done for r in reqs) and events
+    assert not [e for e in events if e.name.startswith("Engine.")]
+    # one shared no-op span: nothing is allocated per step
+    assert NULL_TRACER.phase("Engine.step") is \
+        NULL_TRACER.phase("Engine.dispatch", step=1, kind="decode")
+
+
+def test_step_programs_are_named_by_kind(model_params, profiled):
+    """The paged-hybrid async engine's step programs run as the HLO
+    modules ``jit_step_<kind>``, ``kind`` as in ``StepRecord.kind``."""
+    model, params = model_params
+    (_, eng, tracer), events = profiled(lambda: _serve_traced(
+        model, params, PROMPTS, cache_kind="paged", block_size=8,
+        schedule="hybrid", prefill_chunk=8))
+    kinds = {r.kind for r in tracer.steps}
+    assert {"decode", "fused", "solo", "solo2"} <= kinds
+    modules = {e.stats["hlo_module"] for e in events
+               if str(e.stats.get("hlo_module", "")).startswith("jit_step_")}
+    assert modules == {f"jit_step_{k}" for k in kinds}
+    built = {"decode": eng._decode_sampled, "fused": eng._fused,
+             "solo": eng._solo, "fused2": eng._fused2, "solo2": eng._solo2}
+    for kind, program in built.items():
+        assert program.__name__ == f"step_{kind}"
