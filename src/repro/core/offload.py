@@ -92,8 +92,8 @@ def _paged_kernel(env: Env, q, k_pool, v_pool, tables, lengths, *, scale,
     """Paged decode kernel on the pool's lanes -> ``(out, lse)``.
 
     A lane holding a contiguous run of physical blocks attends only
-    those: table entries it does not hold become ``-1`` (fetched as its
-    block 0 and masked by the kernel), and the lanes' partial outputs
+    those: table entries it does not hold become ``-1`` (not fetched,
+    and masked by the kernel), and the lanes' partial outputs
     merge exactly by log-sum-exp.  A head-sharded pool needs no merge.
     """
     if starts is None:

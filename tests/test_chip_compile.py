@@ -1,5 +1,6 @@
 """Compile the served Pallas kernels for a described TPU v5e, at
-llama3.2-1b widths, with no chip attached.
+llama3.2-1b widths (and the paged kernel at MiniCPM-2B's and Yi-34B's
+too), with no chip attached.
 
 The TPU compiler refuses what interpret mode accepts: block shapes off the
 (8, 128) tiling, too much VMEM.  Each case lowers the kernel for one chip
@@ -70,21 +71,38 @@ def _compiles_kernel(fn, *args) -> None:
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "fp8"])
-def test_paged_decode_compiles(shape, kv_dtype):
+# (slots, kv heads, group, head dim, table entries, pool blocks)
+LLAMA_PAGED = (SLOTS, HKV, G, D, MAX_SEQ // BLOCK_SIZE, N_BLOCKS)
+# the decode-long cell: MiniCPM-2B, MHA with 36 heads of 64
+MINICPM_PAGED = (5, 36, 1, 64, 97, 486)
+YI_PAGED = (5, 8, 7, 128, 97, 486)      # Yi-34B's group 7 at D 128
+
+
+@pytest.mark.parametrize("dims,kv_dtype", [
+    pytest.param(LLAMA_PAGED, "bf16", id="bf16"),
+    pytest.param(LLAMA_PAGED, "int8", id="int8"),
+    pytest.param(LLAMA_PAGED, "fp8", id="fp8"),
+    pytest.param(MINICPM_PAGED, "bf16", id="minicpm-bf16"),
+    pytest.param(MINICPM_PAGED, "int8", id="minicpm-int8"),
+    pytest.param(MINICPM_PAGED, "fp8", id="minicpm-fp8"),
+    pytest.param(YI_PAGED, "bf16", id="yi-bf16"),
+])
+def test_paged_decode_compiles(shape, dims, kv_dtype):
     """Paged decode with a hot-window ``starts`` and the LSE output; the
-    quantized pools carry their (N, Hkv, 1, block_size) scale pools."""
-    pool = shape((N_BLOCKS, HKV, BLOCK_SIZE, D), PAYLOAD[kv_dtype])
+    quantized pools carry their (N, Hkv, 1, block_size) scale pools.  The
+    page buffers must fit the chip's VMEM at each shape."""
+    slots, hkv, g, d, max_blocks, n_blocks = dims
+    pool = shape((n_blocks, hkv, BLOCK_SIZE, d), PAYLOAD[kv_dtype])
     scales = None
     if kv_dtype != "bf16":
-        scales = shape((N_BLOCKS, HKV, 1, BLOCK_SIZE), jnp.float32)
-    fn = functools.partial(paged_decode_attention_pallas, scale=SCALE)
+        scales = shape((n_blocks, hkv, 1, BLOCK_SIZE), jnp.float32)
+    fn = functools.partial(paged_decode_attention_pallas, scale=d ** -0.5)
     _compiles_kernel(
         lambda q, k, v, t, n, st, ks, vs: fn(q, k, v, t, n, starts=st,
                                              k_scale=ks, v_scale=vs),
-        shape((SLOTS, HKV, G, D), jnp.bfloat16), pool, pool,
-        shape((SLOTS, MAX_SEQ // BLOCK_SIZE), jnp.int32),
-        shape((SLOTS,), jnp.int32), shape((SLOTS,), jnp.int32), scales, scales,
+        shape((slots, hkv, g, d), jnp.bfloat16), pool, pool,
+        shape((slots, max_blocks), jnp.int32),
+        shape((slots,), jnp.int32), shape((slots,), jnp.int32), scales, scales,
     )
 
 

@@ -76,8 +76,9 @@ def test_kv_quantize_roundtrip_property(kv_dtype):
 @pytest.mark.parametrize("kv_dtype", ["fp8", "int8"])
 def test_paged_kernel_quantized_matches_oracle(kv_dtype):
     """The in-kernel dequantize path == gather + dequantize + dense
-    oracle, and both sit close to the unquantized attention."""
-    B, Hkv, G, D, bs, MB = 3, 2, 4, 16, 8, 4
+    oracle, and both sit close to the unquantized attention.  Groups of
+    32 pages: the longer lanes reach into a second, partial one."""
+    B, Hkv, G, D, bs, MB = 3, 2, 4, 16, 16, 48
     N = 1 + B * MB
     ks = jax.random.split(jax.random.key(7), 3)
     q = jax.random.normal(ks[0], (B, Hkv * G, D), jnp.float32)
@@ -85,7 +86,7 @@ def test_paged_kernel_quantized_matches_oracle(kv_dtype):
     vp = jax.random.normal(ks[2], (N, Hkv, bs, D), jnp.float32)
     rng = np.random.default_rng(0)
     perm = iter(rng.permutation(np.arange(1, N)))
-    lens = (5, 17, 32)
+    lens = (5, 600, 768)
     tables = np.zeros((B, MB), np.int32)
     for b in range(B):
         for j in range(-(-int(lens[b]) // bs)):
@@ -129,16 +130,18 @@ def test_lse_merge_matches_full_attention_oracle():
 
 def test_lse_merge_kernel_hot_window_matches_oracle():
     """The Pallas kernel's (out, lse) over a ``starts``-restricted hot
-    window merges with a cold-prefix oracle part into full attention."""
-    B, Hkv, G, D, bs, MB = 2, 2, 4, 16, 8, 4
+    window merges with a cold-prefix oracle part into full attention.
+    Groups of 32 pages: lane 0's window starts inside its second group,
+    so the first is never visited; lane 1's spans both."""
+    B, Hkv, G, D, bs, MB = 2, 2, 4, 16, 16, 48
     N = 1 + B * MB
     ks = jax.random.split(jax.random.key(11), 3)
     q = jax.random.normal(ks[0], (B, Hkv * G, D), jnp.float32)
     kp = jax.random.normal(ks[1], (N, Hkv, bs, D), jnp.float32)
     vp = jax.random.normal(ks[2], (N, Hkv, bs, D), jnp.float32)
-    tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
-    lengths = jnp.asarray([29, 32], jnp.int32)
-    starts = jnp.asarray([8, 16], jnp.int32)         # cold: 1 resp. 2 blocks
+    tables = jnp.arange(1, N, dtype=jnp.int32).reshape(B, MB)
+    lengths = jnp.asarray([700, 768], jnp.int32)
+    starts = jnp.asarray([520, 24], jnp.int32)
 
     hot = ops.paged_decode_attention(q, kp, vp, tables, lengths,
                                      starts=starts, return_lse=True)
@@ -148,6 +151,49 @@ def test_lse_merge_kernel_hot_window_matches_oracle():
     full = ref.paged_decode_attention(q, kp, vp, tables, lengths)
     np.testing.assert_allclose(np.asarray(merged), np.asarray(full),
                                atol=2e-4, rtol=2e-4)
+
+
+def test_paged_kernel_skips_blocks_held_elsewhere():
+    """Negative entries mid-table (blocks another lane holds, when the
+    pool is split) are masked, whatever that lane's block 0 holds:
+    attention over a table with holes equals attention over the same
+    table with the holes taken out (no positional term in the kernel).
+    A hot window and a quantized pool take the same path."""
+    B, Hkv, G, D, bs, MB = 2, 2, 4, 16, 16, 48
+    N = 1 + B * MB
+    ks = jax.random.split(jax.random.key(5), 3)
+    q = jax.random.normal(ks[0], (B, Hkv * G, D), jnp.float32)
+    kp = jax.random.normal(ks[1], (N, Hkv, bs, D), jnp.float32)
+    vp = jax.random.normal(ks[2], (N, Hkv, bs, D), jnp.float32)
+    full = np.arange(1, N, dtype=np.int32).reshape(B, MB)
+    lens, starts = (760, 700), (0, 40)
+    holes = {0: [3, 31, 32, 40], 1: [5, 33]}         # whole pages past starts
+    tables, compact = full.copy(), np.zeros_like(full)
+    for b, hs in holes.items():
+        tables[b, hs] = -1
+        kept = [e for j, e in enumerate(full[b, :-(-lens[b] // bs)])
+                if j not in hs]
+        compact[b, :len(kept)] = kept
+    cut = [lens[b] - bs * len(holes[b]) for b in range(B)]
+    nan = jnp.float32(jnp.nan)
+    pools = [(kp, None, vp, None)]
+    pools.append(ref.kv_quantize_pool(kp, "int8") + ref.kv_quantize_pool(vp, "int8"))
+    for kq, k_scale, vq, v_scale in pools:
+        # block 0, where the old kernel fetched a -1 entry from, is poison
+        if k_scale is None:
+            kern = (kq.at[0].set(nan), None, vq.at[0].set(nan), None)
+        else:
+            kern = (kq, k_scale.at[0].set(nan), vq, v_scale.at[0].set(nan))
+        out = ops.paged_decode_attention(
+            q, kern[0], kern[2], jnp.asarray(tables),
+            jnp.asarray(lens, jnp.int32), starts=jnp.asarray(starts, jnp.int32),
+            k_scale=kern[1], v_scale=kern[3])
+        exp = ref.paged_decode_attention(
+            q, kq, vq, jnp.asarray(compact), jnp.asarray(cut, jnp.int32),
+            starts=jnp.asarray(starts, jnp.int32), k_scale=k_scale,
+            v_scale=v_scale)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
+                                   atol=2e-4, rtol=2e-4)
 
 
 # ----------------------------------------------------- pool/manager host tier

@@ -9,6 +9,7 @@ import pytest
 from repro.configs.reduced import reduce_config
 from repro.core.placement import Env
 from repro.kernels import ops, ref
+from repro.kernels import paged_decode_attention as pdk
 from repro.models.registry import build_model
 from repro.serving.engine import Engine, Request
 from repro.serving.paged import BlockPool, PagedCacheManager
@@ -104,6 +105,13 @@ PAGED_CASES = [
     (3, 2, 4, 16, 8, 4, (5, 17, 32)),
     (2, 2, 8, 32, 16, 3, (1, 48)),      # HPU design point G=8
     (2, 1, 3, 16, 8, 4, (9, 25)),       # non-pow2 group
+    # page groups of 32 pages: three with a partial last, one position
+    # into a second, exactly one
+    (3, 2, 4, 16, 16, 70, (1100, 513, 512)),
+    # the decode-long cell's shape (MiniCPM-2B: MHA, 36 heads of 64, 97
+    # entries, 486 blocks): seven groups, an empty slot beside a full one
+    (5, 36, 1, 64, 16, 97, (0, 1552, 700, 1000, 17)),
+    (2, 8, 7, 128, 16, 40, (600, 37)),  # Yi-34B's group 7 at D 128
 ]
 
 
@@ -130,6 +138,37 @@ def test_paged_kernel_matches_oracle(case, dtype):
     np.testing.assert_allclose(
         out.astype(jnp.float32), exp.astype(jnp.float32), atol=tol, rtol=tol
     )
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8, jnp.float8_e4m3fn])
+@pytest.mark.parametrize("hkv,head_dim", [
+    (36, 64),    # MiniCPM-2B: MHA, group 1
+    (8, 64),     # llama3.2-1b: group 4
+    (8, 128),    # Yi-34B: group 7
+])
+def test_page_group_fits_vmem_budget(hkv, head_dim, kv_dtype):
+    """The pages a group gathers come from the shapes: the two buffer
+    slots the kernel allocates (K and V rows padded to 128 lanes, and the
+    f32 scale rows of a quantized pool) fit the VMEM budget, a group
+    spans at least 128 positions of 16-token blocks, and the heads
+    computed together fit theirs."""
+    bs, max_blocks = 16, 97
+    itemsize = np.dtype(kv_dtype).itemsize
+    quantized = itemsize == 1
+    pages = pdk.pages_per_group(hkv, bs, head_dim, itemsize, quantized,
+                                max_blocks)
+    lanes = -(-head_dim // 128) * 128
+    buffers = 2 * 2 * hkv * pages * bs * lanes * itemsize
+    if quantized:
+        buffers += 2 * 2 * hkv * pages * 128 * 4
+    assert buffers <= pdk.VMEM_BUDGET
+    assert 128 <= pages * bs <= pdk.MAX_GROUP_POSITIONS
+    assert pdk.pages_per_group(hkv, bs, head_dim, itemsize, quantized, 3) == 3
+    # the heads computed together divide the heads, and their widened
+    # float32 operand fits its own budget
+    hb = pdk.heads_per_block(hkv, pages * bs, head_dim)
+    assert hkv % hb == 0
+    assert hb * pages * bs * lanes * 4 <= pdk.HEAD_BLOCK_BYTES
 
 
 def test_paged_kernel_ignores_null_block_garbage():
