@@ -44,7 +44,7 @@ def main(argv: list[str]) -> int:
     for name in argv:
         cell = spec.load_cell(name)
         e = cell.engine
-        cfg = spec.model_config(cell.config)
+        cfg = cell.family.model_config(cell.config)
         model = build_model(cfg, Env())
         max_blocks = -(-e["max_seq"] // e["block_size"])
         span = max_blocks * e["block_size"]

@@ -15,13 +15,16 @@ class CompileLog:
         self.n = 0
         self.hits = 0
         self.seconds = 0.0
+        self.names: list[str] = []      # each program's name, in order
         jax.monitoring.register_event_duration_secs_listener(self._on_duration)
         jax.monitoring.register_event_listener(self._on_event)
 
-    def _on_duration(self, event: str, secs: float, **_) -> None:
+    def _on_duration(self, event: str, secs: float, fun_name: str = "?",
+                     **_) -> None:
         if event == COMPILE:
             self.n += 1
             self.seconds += secs
+            self.names.append(fun_name)
 
     def _on_event(self, event: str, **_) -> None:
         if event == HIT:

@@ -5,6 +5,7 @@ read returns None, and the metric is left out of the result line.
 from __future__ import annotations
 
 import dataclasses
+import types
 from typing import Any
 
 import numpy as np
@@ -18,25 +19,22 @@ PAGED_KERNEL = "paged_decode_attention"
 FLASH_KERNEL = "flash_attention"
 
 
-@dataclasses.dataclass
-class Step:
-    """One engine dispatch, from the program's step timeline."""
-
-    step: int
-    wall: float
-    decode_batch: int
-    kv_tokens: int
-    pool_util: float | None
-    chunks: list[tuple[int, int, bool]]      # (pos, n_valid, last)
+class Step(types.SimpleNamespace):
+    """One engine dispatch, from the program's step timeline: every field
+    of its ``StepRecord`` (``as_dict()``: ``step``, ``wall``,
+    ``decode_batch``, ``kv_tokens``, ``pool_util`` and whatever else the
+    program records), and ``chunks``, the dispatch's prefill chunks as
+    ``(pos, n_valid, last)``."""
 
 
 @dataclasses.dataclass
 class Context:
-    shape: Any                      # spec.Shape
+    shape: Any                      # the family's shape(config)
     peak: dict                      # peaks.json entry of this device
     steps: list[Step]               # dispatches in the measured window
     trace: tr.Trace | None = None
     trace_window: tuple[float, float] | None = None   # host clock
+    family: Any = None              # the cell's family module (spec.py)
 
     def traced_steps(self) -> list[Step]:
         lo, hi = self.trace_window
@@ -56,7 +54,7 @@ def mfu(ctx: Context) -> float | None:
     busy = tr.busy_s(ctx.trace)
     if not steps or busy <= 0:
         return None
-    flops = sum(cost.step_flops(ctx.shape, s.decode_batch, s.kv_tokens, s.chunks)
+    flops = sum(ctx.family.step_flops(ctx.shape, s.decode_batch, s.kv_tokens, s.chunks)
                 for s in steps)
     return 100.0 * flops / (busy * ctx.peak["bf16_flops"])
 

@@ -2,7 +2,10 @@
 output token as the client sees it (when ``Engine.step`` returns with it
 observed).
 
-The loop is the only client, and it starts in set-up: ``warm`` drives it
+The loop is the only client, and it starts in set-up.  First
+``warm_chunks`` prefills one prompt as long as each chunk bucket beside a
+decoding lane, which runs every step program that a returning client's
+prompt can end on; then ``warm`` drives the loop
 until every request of a closed loop's opening round has its first token
 and a stretch of engine steps has dispatched no program the process had
 not run before (no compile, no load from the persistent cache).  A
@@ -22,12 +25,15 @@ import dataclasses
 import time
 
 import jax
+import numpy as np
 
 from traffic import Generator
 
 QUIET_STEPS = 16        # steps in a row with no new program end the warm-up
 WARM_CAP_S = 600.0      # the warm-up gives up here; the window counts compiles
-WARM_STREAM = 0x5EED    # an open loop's warm-up draws from seed ^ this
+WARM_STREAM = 0x5EED    # warm-up requests draw from seed ^ this
+WARM_UID = 1 << 30      # uids of warm_chunks' requests, apart from the mix's
+WARM_NEW = 8            # tokens each of them decodes: the lane beside the next
 GRACE_S = 60.0          # past the window, wait this long for first tokens
 
 
@@ -117,6 +123,31 @@ class Load:
             with span("generator wait"):
                 time.sleep(min(wait, 0.05))
         return False
+
+    def warm_chunks(self, lengths: list[int]) -> int:
+        """Prefill one prompt of each length in turn, each beside the lane
+        of the one before, which is still decoding (a first prompt of the
+        last length opens that lane), and serve them all out; returns the
+        engine steps taken.  Given the chunk buckets as the lengths, every
+        step program that a client's prompt, returning inside the window,
+        runs beside decoding lanes is then in memory: a chunk's program is
+        keyed by its bucket alone.  The requests are not tracked: they
+        belong to no client and no result."""
+        rng = np.random.default_rng(self.seed ^ WARM_STREAM)
+        reqs, steps = [], 0
+        for i, n in enumerate([lengths[-1], *lengths]):
+            req = self.Request(uid=WARM_UID + i,
+                               prompt=rng.integers(1, self.vocab, n, dtype=np.int32),
+                               max_new_tokens=WARM_NEW)
+            self.eng.submit(req)
+            reqs.append(req)
+            while not req.out_tokens:
+                self.eng.step()
+                steps += 1
+        while not all(r.done for r in reqs):
+            self.eng.step()
+            steps += 1
+        return steps
 
     def warm(self, new_programs) -> int:
         """Run the load until the opening round has its first tokens and

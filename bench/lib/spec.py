@@ -2,18 +2,40 @@
 configuration and its traffic mix; each lives in a file of its own under
 ``bench/`` and nothing here names a particular one.
 
-    bench/configs/<config>.json    model sizes as run (HF config keys)
+    bench/configs/<config>.json    model sizes as run (HF config keys), with
+                                   the ``family`` and ``reference`` it names
+    bench/families/<family>.py     everything that depends on the block
     bench/traffic/<traffic>.json   loop kind, rate or clients, lengths
     bench/cells/<cell>.json        engine settings sized for this cell
     bench/metrics/<metric>.py      one reader per per-layer metric
     bench/references/<name>.py     plain float32 forward named by a config
     bench/peaks.json               device peaks keyed by device_kind
+
+A family module gives the harness:
+
+    shape(config)                  the sizes the benchmark's own code needs;
+                                   ``.vocab`` is the id range traffic draws
+    model_config(config)           the program's ``ModelConfig``
+    served_params(shape, seed)     the seeded weights, made on the device
+                                   in one jitted call, as the program lays
+                                   them out
+    rehearsal(config)              the configuration at a size the CPU runs
+    step_flops(shape, decode_batch, kv_tokens, chunks)
+                                   one dispatch's FLOPs (``mfu.throughput``)
+
+and the ``(flops, bytes)`` of each of its kernels, for the metric readers
+of their rooflines; its reference takes the module and calls the same
+per-layer and table generators.  Adding a family is new files only: a
+family module, a configuration naming it, a reference, cells, traffic,
+metric readers, and entries in ``BENCHMARK.json``.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
+import sys
 from pathlib import Path
 from typing import Any
 
@@ -30,6 +52,11 @@ class Cell:
     chips: int
     end_to_end: list[dict[str, Any]]     # the metrics this cell reports
     per_layer: list[dict[str, Any]]
+    bench: Path = BENCH                  # the directory its files are in
+
+    @property
+    def family(self):
+        return family_module(self.config, self.bench)
 
 
 def _read_json(path: Path) -> dict[str, Any]:
@@ -42,6 +69,9 @@ def _applies(metric: dict[str, Any], cell: str) -> bool:
 
 
 def load_cell(name: str, root: Path = ROOT) -> Cell:
+    """The cell ``name`` of ``root``'s ``BENCHMARK.json``, with every file
+    it names found under ``root``."""
+    bench = root / "bench"
     spec = _read_json(root / "BENCHMARK.json")
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
@@ -49,32 +79,43 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     w = cells[name]
     configs = {c["name"]: c for c in spec["configs"]}
     config = _read_json(root / configs[w["config"]]["file"])
-    traffic = _read_json(BENCH / "traffic" / f"{w['traffic']}.json")
-    engine = _read_json(BENCH / "cells" / f"{name}.json")
+    traffic = _read_json(bench / "traffic" / f"{w['traffic']}.json")
+    engine = _read_json(bench / "cells" / f"{name}.json")
     e2e = [m for m in spec["end_to_end"] if _applies(m, name)]
     reported = {m["name"] for m in e2e}
     layer = [m for m in spec["per_layer"]
              if _applies(m, name) and m["moves"] in reported]
     return Cell(name=name, config=config, traffic=traffic, engine=engine,
-                chips=int(w["chips"]), end_to_end=e2e, per_layer=layer)
+                chips=int(w["chips"]), end_to_end=e2e, per_layer=layer,
+                bench=bench)
 
 
+@functools.cache
 def load_module(path: Path):
-    """Import one file of the benchmark by path (metric readers,
-    references) without making ``bench`` a package."""
-    mod_name = "bench_" + path.stem.replace("-", "_").replace(".", "_")
+    """Import one file of the benchmark by path (families, metric
+    readers, references) without making ``bench`` a package; each file
+    once, so that its types stay the same across callers.  It is entered
+    in ``sys.modules`` (as ``bench_<dir>_<stem>``), where ``dataclasses``
+    looks up a module's names."""
+    name = f"bench_{path.parent.name}_{path.stem}"
+    mod_name = name.replace("-", "_").replace(".", "_")
     spec = importlib.util.spec_from_file_location(mod_name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[mod_name] = mod
     spec.loader.exec_module(mod)
     return mod
 
 
-def reference_module(config: dict[str, Any]):
-    return load_module(BENCH / "references" / f"{config['reference']}.py")
+def family_module(config: dict[str, Any], bench: Path = BENCH):
+    return load_module(bench / "families" / f"{config['family']}.py")
 
 
-def metric_reader(name: str):
-    return load_module(BENCH / "metrics" / f"{name}.py")
+def reference_module(config: dict[str, Any], bench: Path = BENCH):
+    return load_module(bench / "references" / f"{config['reference']}.py")
+
+
+def metric_reader(name: str, bench: Path = BENCH):
+    return load_module(bench / "metrics" / f"{name}.py")
 
 
 def peaks(device_kind: str) -> dict[str, Any]:
@@ -83,64 +124,3 @@ def peaks(device_kind: str) -> dict[str, Any]:
         raise SystemExit(f"no peaks for device_kind {device_kind!r} in "
                          f"bench/peaks.json; known: {sorted(table['devices'])}")
     return table["devices"][device_kind]
-
-
-@dataclasses.dataclass(frozen=True)
-class Shape:
-    """The sizes the benchmark's own code needs (cost model, reference,
-    weights), read from the HF-style configuration file."""
-
-    n_layers: int
-    d_model: int
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    padded_vocab: int
-    rope_theta: float
-    norm_eps: float
-    tied: bool
-
-    @property
-    def kv_bytes_per_token(self) -> int:
-        return 2 * self.n_layers * self.n_kv_heads * self.head_dim * 2
-
-    @property
-    def n_params(self) -> int:
-        D, Dh = self.d_model, self.head_dim
-        attn = D * (self.n_heads + 2 * self.n_kv_heads) * Dh + self.n_heads * Dh * D
-        layer = attn + 3 * D * self.d_ff + 2 * D
-        tables = (1 if self.tied else 2) * self.padded_vocab * D
-        return self.n_layers * layer + tables + D
-
-
-def shape(config: dict[str, Any], vocab_multiple: int = 256) -> Shape:
-    c = config
-    heads = c["num_attention_heads"]
-    vocab = c["vocab_size"]
-    return Shape(
-        n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        n_heads=heads, n_kv_heads=c["num_key_value_heads"],
-        head_dim=c.get("head_dim", c["hidden_size"] // heads),
-        d_ff=c["intermediate_size"], vocab=vocab,
-        padded_vocab=-(-vocab // vocab_multiple) * vocab_multiple,
-        rope_theta=float(c["rope_theta"]), norm_eps=float(c["rms_norm_eps"]),
-        tied=bool(c["tie_word_embeddings"]),
-    )
-
-
-def model_config(config: dict[str, Any]):
-    """The program's ``ModelConfig`` for a configuration file (imports
-    the system under test)."""
-    from repro.configs.base import DENSE, ModelConfig
-
-    s = shape(config)
-    return ModelConfig(
-        name=config["name"], family=DENSE, n_layers=s.n_layers,
-        d_model=s.d_model, n_heads=s.n_heads, n_kv_heads=s.n_kv_heads,
-        d_ff=s.d_ff, vocab=s.vocab, head_dim=s.head_dim,
-        max_seq=config["max_position_embeddings"], rope_theta=s.rope_theta,
-        norm_eps=s.norm_eps, tie_embeddings=s.tied,
-        dtype=config["torch_dtype"],
-    )

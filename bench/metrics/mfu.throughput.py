@@ -1,6 +1,6 @@
 """Whole-step model FLOP utilisation: analytic FLOPs of every token the
-traced dispatches processed (``lib/cost.py``), over the device's busy
-seconds in the trace times the bf16 peak, in percent."""
+traced dispatches processed (the family's ``step_flops``), over the
+device's busy seconds in the trace times the bf16 peak, in percent."""
 import layer
 
 

@@ -6,9 +6,10 @@ grouped-query causal softmax attention scaled by ``1/sqrt(head_dim)``, an
 output projection, a second RMSNorm and a SwiGLU MLP, both on the
 residual stream; a final RMSNorm and the output head (the embedding when
 tied).  No kernel, cache or batching of the program is used: weights come
-from the benchmark's own seeded generator, one layer at a time, so the
-reference fits beside nothing else on the device once the program has
-been freed.
+from the benchmark's own seeded generator (the ``dense`` family's
+``layer_leaves`` and ``table_leaves``, ``bench/families/dense.py``), one
+layer at a time, so the reference fits beside nothing else on the device
+once the program has been freed.
 
 ``precision="fp8"`` is the control: every matmul operand is rounded to
 float8 e4m3 with a per-tensor scale (weights) or per-row scale
@@ -24,7 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 
 import weights
-from spec import Shape
 
 Q_BLOCK = 512
 E4M3_MAX = 448.0
@@ -59,7 +59,7 @@ def _rope(x, pos, theta):
 
 
 @functools.partial(jax.jit, static_argnames=("s", "fp8"))
-def _layer(h, w, *, s: Shape, fp8: bool):
+def _layer(h, w, *, s, fp8: bool):
     """h (N, S, D) float32 -> next residual stream."""
     N, S, D = h.shape
     G = s.n_heads // s.n_kv_heads
@@ -87,12 +87,12 @@ def _layer(h, w, *, s: Shape, fp8: bool):
 
 
 @functools.partial(jax.jit, static_argnames=("s",))
-def _embed(tokens, tables, *, s: Shape):
+def _embed(tokens, tables, *, s):
     return tables["embed"].astype(jnp.float32)[tokens]
 
 
 @functools.partial(jax.jit, static_argnames=("s", "fp8"))
-def _head(h, rows, tables, *, s: Shape, fp8: bool):
+def _head(h, rows, tables, *, s, fp8: bool):
     """Logits at the gathered rows: h (N, S, D), rows (N, R) -> (N, R, V)."""
     x = jnp.take_along_axis(h, rows[..., None], axis=1)
     x = _rmsnorm(x, tables["final_norm"].astype(jnp.float32), s.norm_eps)
@@ -104,24 +104,25 @@ def _bucket(n: int, mult: int) -> int:
     return -(-n // mult) * mult
 
 
-def logits_at(s: Shape, seed: int, seqs: list[np.ndarray],
+def logits_at(family, s, seed: int, seqs: list[np.ndarray],
               rows: list[np.ndarray], precision: str = "f32"):
     """Logits of each sequence at the given positions, one sequence and
-    one layer at a time.  Sequences are padded at the end to one length
+    one layer at a time, with the weights ``family`` makes for the sizes
+    ``s`` (its ``shape``) and ``seed``.  Sequences are padded at the end to one length
     (causal attention leaves the real positions unchanged), so the few
     programs compiled here are shared across runs.  Yields one
     ``(len(rows[i]), vocab)`` device array per sequence."""
     fp8 = precision == "fp8"
     S = _bucket(max(len(t) for t in seqs), Q_BLOCK)
     R = _bucket(max(len(r) for r in rows), 128)
-    tables = jax.jit(lambda k: weights.table_leaves(s, k))(weights.table_key(seed))
+    tables = jax.jit(lambda k: family.table_leaves(s, k))(weights.table_key(seed))
     with jax.default_matmul_precision("highest"):
         hs = []
         for t in seqs:
             tok = np.zeros((1, S), np.int32)
             tok[0, :len(t)] = t
             hs.append(_embed(jnp.asarray(tok), tables, s=s))
-        make = jax.jit(lambda k: weights.layer_leaves(s, k))
+        make = jax.jit(lambda k: family.layer_leaves(s, k))
         for layer in range(s.n_layers):
             w = make(weights.layer_key(seed, layer))
             hs = [_layer(h, w, s=s, fp8=fp8) for h in hs]

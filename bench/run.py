@@ -53,15 +53,10 @@ SPANS = ("submit", "engine.step", "generator wait")
 LAST: dict = {}             # the last run's sample and end-to-end numbers
 
 
-REHEARSAL = dict(hidden_size=64, num_attention_heads=4, intermediate_size=128,
-                 num_hidden_layers=2, vocab_size=500)
-
-
 def rehearsal_cell(cell: spec.Cell) -> spec.Cell:
-    """The cell at a size the CPU runs in seconds (tests only)."""
-    c = dict(cell.config)
-    group = c["num_attention_heads"] // c["num_key_value_heads"]
-    c.update(REHEARSAL, num_key_value_heads=4 if group == 1 else 2)
+    """The cell at a size the CPU runs in seconds (tests only): the
+    family's rehearsal configuration, short lengths, a small pool."""
+    c = cell.family.rehearsal(cell.config)
     t = json.loads(json.dumps(cell.traffic))
     for key, top in (("prompt", 48), ("output", 6)):
         d = t[key]
@@ -78,7 +73,7 @@ def rehearsal_cell(cell: spec.Cell) -> spec.Cell:
              check=cell.engine.get("check", {}))
     return spec.Cell(name=cell.name, config=c, traffic=t, engine=e,
                      chips=1, end_to_end=cell.end_to_end,
-                     per_layer=cell.per_layer)
+                     per_layer=cell.per_layer, bench=cell.bench)
 
 
 def setup_jax(rehearse: bool):
@@ -97,14 +92,14 @@ def setup_jax(rehearse: bool):
 
 
 def build(cell: spec.Cell, seed: int, tracer, async_mode: bool = True):
-    import weights
     from repro.core.placement import Env
     from repro.models.registry import build_model
     from repro.serving.engine import Engine
 
-    shape = spec.shape(cell.config)
-    model = build_model(spec.model_config(cell.config), Env())
-    params = weights.served_params(shape, seed)
+    family = cell.family
+    shape = family.shape(cell.config)
+    model = build_model(family.model_config(cell.config), Env())
+    params = family.served_params(shape, seed)
     e = cell.engine
     eng = Engine(model, params, n_slots=e["n_slots"], max_seq=e["max_seq"],
                  cache_kind="paged", block_size=e["block_size"],
@@ -116,8 +111,9 @@ def build(cell: spec.Cell, seed: int, tracer, async_mode: bool = True):
 
 
 def steps_from(tracer, lo: float, hi: float) -> list:
-    """The program's step timeline in [lo, hi], with each dispatch's
-    prefill chunks (``prefill_chunk`` spans end at their dispatch step)."""
+    """The program's step timeline in [lo, hi], every field of each
+    ``StepRecord``, with each dispatch's prefill chunks
+    (``prefill_chunk`` spans end at their dispatch step)."""
     import layer
 
     chunks: dict[int, list] = {}
@@ -126,9 +122,7 @@ def steps_from(tracer, lo: float, hi: float) -> list:
             a = sp.attrs
             chunks.setdefault(sp.end, []).append(
                 (int(a["pos"]), int(a["n_valid"]), bool(a["last"])))
-    return [layer.Step(step=r.step, wall=r.wall, decode_batch=r.decode_batch,
-                       kv_tokens=r.kv_tokens, pool_util=r.pool_util,
-                       chunks=chunks.get(r.step, []))
+    return [layer.Step(**r.as_dict(), chunks=chunks.get(r.step, []))
             for r in tracer.steps if r.wall is not None and lo <= r.wall <= hi]
 
 
@@ -181,19 +175,21 @@ def logit_gaps(cell: spec.Cell, seed: int, sample, precision="f32"):
     import jax.numpy as jnp
     import numpy as np
 
-    ref = spec.reference_module(cell.config)
-    s = spec.shape(cell.config)
+    ref = spec.reference_module(cell.config, cell.bench)
+    family = cell.family
+    s = family.shape(cell.config)
     seqs = [np.concatenate([p, o[:-1]]) for p, o in sample]
     rows = [np.arange(len(p) - 1, len(p) - 1 + len(o)) for p, o in sample]
     out = []
     if precision == "f32":
-        for lg, (_, o) in zip(ref.logits_at(s, seed, seqs, rows), sample):
+        for lg, (_, o) in zip(ref.logits_at(family, s, seed, seqs, rows),
+                              sample):
             best = jnp.max(lg, -1)
             got = jnp.take_along_axis(lg, jnp.asarray(o)[:, None], -1)[:, 0]
             out.append(np.asarray(best - got))
         return out
-    for lg, lc in zip(ref.logits_at(s, seed, seqs, rows),
-                      ref.logits_at(s, seed, seqs, rows, precision)):
+    for lg, lc in zip(ref.logits_at(family, s, seed, seqs, rows),
+                      ref.logits_at(family, s, seed, seqs, rows, precision)):
         pick = jnp.argmax(lc, -1)
         out.append(np.asarray(jnp.max(lg, -1)
                               - jnp.take_along_axis(lg, pick[:, None], -1)[:, 0]))
@@ -241,9 +237,11 @@ class Traced:
             self.jax.profiler.stop_trace()
 
 
-def run(args, mix: dict | None = None) -> tuple[int, dict | None]:
-    """One run; ``mix`` replaces keys of the traffic mix (the rate sweep)."""
-    cell = spec.load_cell(args.workload)
+def run(args, mix: dict | None = None,
+        root: Path = spec.ROOT) -> tuple[int, dict | None]:
+    """One run of the cell as ``root``'s files define it; ``mix``
+    replaces keys of the traffic mix (the rate sweep)."""
+    cell = spec.load_cell(args.workload, root)
     if args.rehearse:
         cell = rehearsal_cell(cell)
     cell.traffic.update(mix or {})
@@ -261,6 +259,7 @@ def run(args, mix: dict | None = None) -> tuple[int, dict | None]:
     from compile_log import CompileLog
     from load import Load
     from repro.serving.engine import Request
+    from repro.serving.scheduler import chunk_buckets
     from repro.serving.telemetry import Tracer
     from traffic import check_mix
 
@@ -275,8 +274,9 @@ def run(args, mix: dict | None = None) -> tuple[int, dict | None]:
     jax.block_until_ready(eng.params)
     t_built = time.perf_counter()
     nb, hb, _ = clog.mark()
-    load.start(t_built)
-    n_steps = load.warm(lambda: clog.mark()[0])
+    n_steps = load.warm_chunks(chunk_buckets(cell.engine["prefill_chunk"]))
+    load.start(time.perf_counter())
+    n_steps += load.warm(lambda: clog.mark()[0])
     n0, h0, _ = clog.mark()
 
     trace_dir = CACHE / "trace"
@@ -294,7 +294,8 @@ def run(args, mix: dict | None = None) -> tuple[int, dict | None]:
         window.tick(math.inf)
     n1, h1, _ = clog.mark()
     print(f"bench: {cell.name} seed {args.seed}: {n1 - n0} programs "
-          f"compiled or loaded ({h1 - h0} from the cache) in the window; "
+          f"compiled or loaded ({h1 - h0} from the cache) in the window "
+          f"{clog.names[n0:n1]}; "
           f"generator lag p99 {layer.percentile(load.lag or [0], 99):.4f}s",
           file=sys.stderr)
     e2e = end_to_end(load, t0, t_end)
@@ -310,7 +311,7 @@ def run(args, mix: dict | None = None) -> tuple[int, dict | None]:
     result: dict = {}
     if args.trace:
         ctx = layer.Context(
-            shape=shape, peak=peak,
+            family=cell.family, shape=shape, peak=peak,
             steps=steps_from(tracer, t0, t_end),
             trace_window=(window.lo, window.hi))
         dev_extra, breakdown = reduce_trace(trace_dir, ctx)
@@ -320,7 +321,7 @@ def run(args, mix: dict | None = None) -> tuple[int, dict | None]:
         device.update(dev_extra)
         metrics = {}
         for m in cell.per_layer:
-            v = spec.metric_reader(m["name"]).read(ctx)
+            v = spec.metric_reader(m["name"], cell.bench).read(ctx)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         if breakdown:

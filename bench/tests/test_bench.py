@@ -61,10 +61,11 @@ def test_cells_found_by_name():
 
 def test_stratified_lengths_are_the_same_set_for_every_seed():
     mix = json.loads((BENCH / "traffic" / "decode-long.json").read_text())
+    n = int(mix.get("strata", traffic.STRATA))
     a = traffic.Generator(mix, 1, 1000)
     b = traffic.Generator(mix, 2**33 + 1, 1000)
-    la = sorted(len(a.next().prompt) for _ in range(traffic.STRATA))
-    lb = sorted(len(b.next().prompt) for _ in range(traffic.STRATA))
+    la = sorted(len(a.next().prompt) for _ in range(n))
+    lb = sorted(len(b.next().prompt) for _ in range(n))
     assert la == lb
     assert mix["prompt"]["min"] <= la[0] and la[-1] <= mix["prompt"]["max"]
 
@@ -199,13 +200,12 @@ def fixed_requests(seed: int, async_mode: bool, steps: int = 200):
     from repro.serving.engine import Request
 
     base = spec.load_cell(CELLS[0])
-    saved = dict(run.REHEARSAL)
-    run.REHEARSAL.update(hidden_size=256, num_hidden_layers=4,
-                         intermediate_size=512, vocab_size=2000)
-    try:
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(base.family, "REHEARSAL",
+                   {**base.family.REHEARSAL, "hidden_size": 256,
+                    "num_hidden_layers": 4, "intermediate_size": 512,
+                    "vocab_size": 2000})
         cell = run.rehearsal_cell(base)
-    finally:
-        run.REHEARSAL.update(saved)
     run.setup_jax(True)
     shape, eng = run.build(cell, seed, None, async_mode=async_mode)
     load = Load(eng, Request, cell.traffic, seed, shape.vocab,
@@ -238,3 +238,36 @@ def test_fixed_requests_without_async_match_the_reference(capsys):
         print(f"\nseed 14, fixed requests: async_mode=False gap {sync:.4f}, "
               f"async_mode=True gap {dispatch_ahead:.4f}, limit {limit}")
     assert sync <= limit
+
+
+def test_warm_chunks_leave_nothing_to_compile_for_a_returning_client():
+    """After ``Load.warm_chunks``, a prompt of any length the chunk
+    buckets cover, prefilled beside a decoding lane as a client that
+    returns inside the window is, runs only programs already in memory."""
+    from compile_log import CompileLog
+    from load import Load
+    from repro.serving.engine import Request
+    from repro.serving.scheduler import chunk_buckets
+
+    cell = run.rehearsal_cell(spec.load_cell(CELLS[0]))
+    run.setup_jax(True)
+    shape, eng = run.build(cell, 31, None)
+    clog = CompileLog()
+    load = Load(eng, Request, cell.traffic, 31, shape.vocab, 1)
+    chunk = cell.engine["prefill_chunk"]
+    load.warm_chunks(chunk_buckets(chunk))
+    rng = np.random.default_rng(31)
+    lane = Request(uid=1, prompt=rng.integers(1, shape.vocab, chunk, dtype=np.int32),
+                   max_new_tokens=3 * chunk)
+    eng.submit(lane)
+    while not lane.out_tokens:
+        eng.step()
+    n0 = clog.mark()[0]
+    for n in (chunk + 1, chunk + chunk // 2, chunk + chunk // 2 + 1, 2 * chunk):
+        req = Request(uid=1 + n, prompt=rng.integers(1, shape.vocab, n, dtype=np.int32),
+                      max_new_tokens=1)
+        eng.submit(req)
+        while not req.done:
+            eng.step()
+        assert not lane.done
+    assert clog.names[n0:] == []
